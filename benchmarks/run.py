@@ -28,6 +28,9 @@ def main() -> None:
                          "(default BENCH_smoke.json with --smoke)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_dataset_size, bench_execution_time,
                             bench_kernels, bench_mspca_denoise,
                             bench_prediction_timeline, bench_serving,

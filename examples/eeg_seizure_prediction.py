@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.eeg_paper import CONFIG
+from repro.launch.mesh import make_data_mesh
 from repro.signal import eeg_data, pipeline
 
 
@@ -43,7 +44,7 @@ def main() -> None:
 
     # --- signal processing as a MapReduce job (the paper's map phase) ----
     t0 = time.time()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     feats = pipeline.process_recording_mapreduce(mesh, rec, CONFIG)
     print(f"[eeg] MapReduce signal processing: {feats.shape} features "
           f"in {time.time() - t0:.1f}s")

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_NAMES, get_config
 from repro.configs.base import InputShape
 from repro.data.synthetic import make_batch
+from repro.launch.mesh import make_data_mesh
 from repro.models import build
 from repro.optim import AdamWConfig, adamw
 from repro.training.trainer import (ensemble_init, make_ensemble_train_step)
@@ -33,7 +34,7 @@ def main() -> None:
     cfg = get_config(args.arch).reduced()
     model = build(cfg)
     opt = adamw(AdamWConfig(lr=1e-3))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     print(f"[ensemble] {args.members} x {cfg.name} "
           f"({model.param_count():,} params each)")
 

@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from repro.core import rotation_forest as rf
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import AlarmRaised, ChunkScored, ScoringProgram, SeizureEngine
 from repro.signal import eeg_data, pipeline
 
@@ -47,6 +48,7 @@ def main() -> None:
                     help="cross-chunk MSPCA halo windows (0 = the "
                          "paper's fully independent chunk denoise)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = pipeline.PipelineConfig(
         forest=rf.RotationForestConfig(

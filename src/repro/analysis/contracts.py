@@ -342,12 +342,14 @@ def _pallas_calls(art: TraceArtifacts):
 def _int_block_dims(block_mapping):
     """(block_shape ints aligned to array dims) for one BlockMapping."""
     block = tuple(block_mapping.block_shape)
-    array = tuple(block_mapping.array_shape_dtype.shape)
-    # block_shape may carry non-int sentinels for squeezed dims; align
-    # from the right, which is how Pallas pairs them.
+    array = tuple(block_mapping.array_aval.shape)
+    # Blocked dims carry their ``block_size``; squeezed dims have none
+    # and pair as None. Align from the right, which is how Pallas pairs
+    # them.
     pairs = []
     for b, d in zip(block[::-1], array[::-1]):
-        pairs.append((b if isinstance(b, int) else None, d))
+        size = getattr(b, "block_size", b)
+        pairs.append((size if isinstance(size, int) else None, d))
     return pairs[::-1]
 
 

@@ -282,7 +282,7 @@ def _kernel_entries():
         name="kernels.forest.forest_predict_proba",
         fn=forest_ops.forest_predict_proba,
         args=(packed, _sds((16, f_raw))),
-        static_kwargs=dict(use_pallas=True, block_b=8, interpret=True),
+        static_kwargs=dict(use_pallas=True, block_b=128, interpret=True),
         description="packed-forest Pallas traversal (one (B, T) pass)",
     )
     t, n_rows, f, nc = 2, 64, 9, cfg.forest.n_classes

@@ -57,7 +57,7 @@ class DistributedEnsemble:
                 jax.tree.map(lambda t: t[None], params), axis
             )
 
-        fn = mr.shard_map(
+        fn = jax.shard_map(
             job, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(),
             check_vma=False,
         )

@@ -24,37 +24,11 @@ Hadoop places on combiners); we provide the common ones.
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-# ``shard_map`` moved from jax.experimental to the jax namespace (>= 0.6),
-# and the replication-check kwarg was renamed check_rep -> check_vma along
-# the way. Resolve both at import time so the rest of the repo can call
-# ``mr.shard_map(..., check_vma=...)`` on any jax >= 0.4.
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map_impl).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """Version-compatible ``shard_map``: accepts the modern ``check_vma``
-    name and forwards it under whichever name the installed jax uses."""
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
 
 MapFn = Callable[..., Any]  # (shard_data...) -> mapped pytree
 ReduceFn = Callable[[Any, str], Any]  # (mapped, axis_name) -> reduced pytree
@@ -125,7 +99,7 @@ class MapReduce:
             mapped = self.map_fn(*args)
             return self.reduce_fn(mapped, axis)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             job, mesh=mesh, in_specs=in_specs, out_specs=P(),
             check_vma=False,
         )

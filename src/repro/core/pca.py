@@ -41,7 +41,8 @@ def _sym_cov(xc: jax.Array, use_kernel: bool = False) -> jax.Array:
 
         g = gram_ops.gram(xc)
     else:
-        g = jnp.einsum("nf,ng->fg", xc, xc, preferred_element_type=jnp.float32)
+        g = jnp.einsum("nf,ng->fg", xc, xc, preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
     return g / jnp.maximum(n - 1, 1)
 
 
@@ -83,7 +84,8 @@ def fit_T(xT: jax.Array) -> PCAState:
     mean = jnp.mean(xT, axis=1)
     xc = xT - mean[:, None]
     cov = jnp.einsum(
-        "pn,qn->pq", xc, xc, preferred_element_type=jnp.float32
+        "pn,qn->pq", xc, xc, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     ) / jnp.maximum(xT.shape[1] - 1, 1)
     evals, evecs = _eig_sorted(cov)
     return PCAState(components=evecs, mean=mean, variances=jnp.maximum(evals, 0.0))

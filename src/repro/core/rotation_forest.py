@@ -21,7 +21,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import decision_tree as dt
+from repro.core import pca
 from repro.kernels.forest import ops as forest_ops
+
+# The rotation covariance (before eigh) and the rotated features (whose
+# quantiles become the split thresholds) decide discrete outcomes, so
+# their matmuls run at f32 precision: on the TPU the default would be a
+# single bf16 pass. A no-op on the CPU.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 class RotationForestConfig(NamedTuple):
@@ -74,10 +81,10 @@ def _build_rotation(key: jax.Array, x: jax.Array, cfg: RotationForestConfig) -> 
         wsum = jnp.maximum(jnp.sum(mask), 2.0)
         mean = jnp.sum(xb * mask[:, None], 0) / wsum
         xc = (xb - mean) * mask[:, None]
-        cov = xc.T @ xc / (wsum - 1.0)
-        evals, evecs = jnp.linalg.eigh(cov)
-        order = jnp.argsort(-evals)
-        return jnp.take(evecs, order, axis=1)  # (M, M), all components kept
+        cov = jnp.matmul(xc.T, xc, precision=_EXACT) / (wsum - 1.0)
+        # Descending components with pca's sign convention: eigh fixes no
+        # eigenvector's sign, and backends pick them differently.
+        return pca._eig_sorted(cov)[1]  # (M, M), all components kept
 
     comps = jax.vmap(block_pca)(boot_keys, blocks)  # (K, M, M)
 
@@ -101,7 +108,7 @@ def _prepare_one(key: jax.Array, x: jax.Array, y: jax.Array, cfg: RotationForest
     """
     rot_key, tree_key = jax.random.split(key)
     rot = _build_rotation(rot_key, x, cfg)
-    xr = x @ rot
+    xr = jnp.matmul(x, rot, precision=_EXACT)
     # Per-tree bootstrap of training instances (bagging on top of rotation,
     # as in the Weka implementation the paper used).
     w = (
@@ -236,7 +243,8 @@ def predict_proba_per_tree(params: RotationForestParams, x: jax.Array) -> jax.Ar
     n_trees = params.rotation.shape[0]
     probs = [
         dt.predict_proba(
-            jax.tree.map(lambda t: t[i], params.trees), x @ params.rotation[i]
+            jax.tree.map(lambda t: t[i], params.trees),
+            jnp.matmul(x, params.rotation[i], precision=_EXACT),
         )
         for i in range(n_trees)
     ]

@@ -34,6 +34,7 @@ def _gram_kernel(x_i_ref, x_j_ref, out_ref):
         xi, xj,
         dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

@@ -10,17 +10,18 @@ Grid: (T, F, N / block_n) with the sample axis innermost, so each
 (n_buckets, C) output tile stays resident in VMEM while every sample
 slab accumulates into it -- the output is written once per (tree,
 feature) instead of once per slab. Per step the kernel materializes the
-(block_n, n_buckets) one-hot bucket matrix with a branch-free VPU
-compare against a broadcasted iota and contracts it against the slab's
-(block_n, C) class-mass tile on the MXU.
+(n_buckets, block_n) transposed one-hot bucket matrix with a
+branch-free VPU compare of a lane-dense (1, block_n) code row against a
+broadcasted iota and contracts it against the slab's (block_n, C)
+class-mass tile on the MXU at f32 precision (the histogram decides
+splits, so no bf16 pass).
 
-VMEM per step (f32): codes (block_n, 1) + wy (block_n, C) + onehot
-(block_n, n_buckets) + out (n_buckets, C). Worst case in this repo
+VMEM per step (f32): codes (1, block_n) + wy (block_n, C) + onehot
+(n_buckets, block_n) + out (n_buckets, C). Worst case in this repo
 (depth-6 level 5, 32 bins: n_buckets = 1024, block_n = 256) is ~1.3 MiB
 -- comfortable with double buffering. The output tile's last dim is C
-(= 2 for seizure scoring), the same narrow-tile caveat as
-kernels/forest; CI exercises interpret mode, TPU block-shape validation
-rides the existing ROADMAP item.
+(= 2 for seizure scoring); ``tests/test_tpu_compile.py`` compiles the
+kernel for a v5e chip at the paper's widths.
 """
 
 from __future__ import annotations
@@ -34,20 +35,21 @@ from jax.experimental import pallas as pl
 
 def _hist_kernel(codes_ref, wy_ref, out_ref, *, n_buckets: int):
     i = pl.program_id(2)
-    codes = codes_ref[0]                     # (block_n, 1) int32
-    wy = wy_ref[0]                           # (block_n, C) f32
-    block_n = codes.shape[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (block_n, n_buckets), 1)
-    onehot = (codes == iota).astype(jnp.float32)   # (block_n, B)
-    part = jnp.dot(onehot.T, wy, preferred_element_type=jnp.float32)
+    codes = codes_ref[...]                   # (1, block_n) int32
+    wy = wy_ref[...]                         # (block_n, C) f32
+    block_n = codes.shape[1]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n_buckets, block_n), 0)
+    onehot_t = (iota == codes).astype(jnp.float32)   # (B, block_n)
+    part = jnp.dot(onehot_t, wy, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(i == 0)
     def _init():
-        out_ref[0, 0] = part
+        out_ref[...] = part
 
     @pl.when(i > 0)
     def _accum():
-        out_ref[0, 0] = out_ref[0, 0] + part
+        out_ref[...] = out_ref[...] + part
 
 
 @functools.partial(
@@ -63,7 +65,8 @@ def class_histogram(
 ) -> jax.Array:
     """codes (T, N, F) int32 bucket ids, wy (T, N, C) f32 class mass
     -> (T, F, n_buckets, C) f32 (same contract as ref.class_histogram).
-    N is padded to a block multiple; out-of-range codes are ignored."""
+    N is padded to a block multiple; out-of-range codes are ignored.
+    On the TPU block_n must be a multiple of 128 (the lane width)."""
     t, n, f = codes.shape
     c = wy.shape[-1]
     pad = (-n) % block_n
@@ -73,17 +76,22 @@ def class_histogram(
                         constant_values=-1)
         wy = jnp.pad(wy, ((0, 0), (0, pad), (0, 0)))
     n_blocks = codes.shape[1] // block_n
+    # Sample-minor codes, (T, F, 1, N): each step reads one lane-dense
+    # (1, block_n) row of one (tree, feature) pair.
+    codes_t = jnp.swapaxes(codes.astype(jnp.int32), 1, 2)[:, :, None, :]
 
     return pl.pallas_call(
         functools.partial(_hist_kernel, n_buckets=n_buckets),
         grid=(t, f, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, block_n, 1), lambda ti, fi, ni: (ti, ni, fi)),
-            pl.BlockSpec((1, block_n, c), lambda ti, fi, ni: (ti, ni, 0)),
+            pl.BlockSpec(
+                (None, None, 1, block_n), lambda ti, fi, ni: (ti, fi, 0, ni)
+            ),
+            pl.BlockSpec((None, block_n, c), lambda ti, fi, ni: (ti, ni, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, n_buckets, c), lambda ti, fi, ni: (ti, fi, 0, 0)
+            (None, None, n_buckets, c), lambda ti, fi, ni: (ti, fi, 0, 0)
         ),
         out_shape=jax.ShapeDtypeStruct((t, f, n_buckets, c), jnp.float32),
         interpret=interpret,
-    )(codes.astype(jnp.int32), wy.astype(jnp.float32))
+    )(codes_t, wy.astype(jnp.float32))

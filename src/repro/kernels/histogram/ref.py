@@ -50,8 +50,9 @@ def block_histogram(codes: jax.Array, wy: jax.Array, n_buckets: int) -> jax.Arra
 
     def one(args):
         codes_tf, wy_t = args
-        onehot = (codes_tf[:, None] == iota).astype(jnp.float32)  # (n, B)
-        return jnp.dot(onehot.T, wy_t, preferred_element_type=jnp.float32)
+        onehot_t = (iota[:, None] == codes_tf[None, :]).astype(jnp.float32)
+        return jnp.dot(onehot_t, wy_t, preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)  # (B, C)
 
     out = jax.lax.map(one, (codes_flat, wy_rep))  # (t*f, B, C)
     return out.reshape(t, f, n_buckets, c)

@@ -23,6 +23,7 @@ from repro import checkpoint as ckpt
 from repro.configs import ARCH_NAMES, get_config
 from repro.configs.base import InputShape
 from repro.data.synthetic import make_batch
+from repro.launch.mesh import make_data_mesh
 from repro.models import build
 from repro.optim import AdamWConfig, adamw, cosine_warmup
 from repro.training import TrainState, make_train_step
@@ -58,7 +59,7 @@ def main() -> None:
     shape = InputShape("cli", args.seq, args.batch, "train")
 
     if args.ensemble:
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         state = ensemble_init(model, opt, rng, args.ensemble)
         step = jax.jit(make_ensemble_train_step(model, opt, mesh,
                                                 args.ensemble))

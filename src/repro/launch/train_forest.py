@@ -2,7 +2,7 @@
 
 Trains a rotation forest MapReduce-style on the synthetic Freiburg
 stand-ins (each shard denoises + featurizes + fits a sub-forest; global
-feature moments via psum; union reduce), freezes it into a
+feature moments combined across shards; union reduce), freezes it into a
 ``ScoringProgram`` through the checkpoint store, loads it back, and
 streams a held-out chronological timeline through a ``SeizureEngine``
 session -- asserting the served alarms match the offline
@@ -11,10 +11,11 @@ session -- asserting the served alarms match the offline
   PYTHONPATH=src python -m repro.launch.train_forest --patient 3 \
       --shards 2 --save-dir /tmp/seizure_ckpt [--devices 2] [--trees 8]
 
-``--shards S`` uses the single-device vmap emulation (bit-identical to
-an S-device mesh); ``--devices N`` forces N host placeholder devices and
-runs the REAL ``shard_map`` job on a data mesh instead (must be the
-first jax touch of the process, so it is set before any jax import).
+``--shards S`` uses the single-device emulation (bit-identical to an
+S-device mesh); ``--devices N`` runs the REAL ``shard_map`` job on a data
+mesh of the first N devices instead. On the CPU backend it forces N host
+devices (must be the first jax touch of the process, so it is set before
+any jax import); on a TPU host the mesh takes N chips.
 """
 
 from __future__ import annotations
@@ -30,10 +31,11 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--patient", type=int, default=3)
     ap.add_argument("--shards", type=int, default=2,
-                    help="map tasks (vmap emulation unless --devices)")
+                    help="map tasks (one-device emulation unless --devices)")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host devices and run the real shard_map "
-                         "mesh job (0 = emulate --shards on one device)")
+                    help="run the real shard_map job on N devices, forced "
+                         "host devices on the CPU backend (0 = emulate "
+                         "--shards on one device)")
     ap.add_argument("--trees", type=int, default=8)
     ap.add_argument("--depth", type=int, default=5)
     ap.add_argument("--bins", type=int, default=16)
@@ -73,8 +75,12 @@ def main() -> None:
     import numpy as np
 
     from repro.core import rotation_forest as rf
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_data_mesh
     from repro.serving import ChunkScored, ScoringProgram, SeizureEngine
     from repro.signal import eeg_data, pipeline
+
+    enable_compile_cache()
 
     per = eeg_data.WINDOWS_PER_MATRIX
     cfg = pipeline.PipelineConfig(
@@ -96,7 +102,7 @@ def main() -> None:
     # shard is class-balanced (a single-class shard grows constant trees).
     rec = eeg_data.stratify_chunks(rec)
     if args.devices > 0:
-        mesh = jax.make_mesh((args.devices,), ("data",))
+        mesh = make_data_mesh(args.devices)
         shards, fit_kwargs = args.devices, {"mesh": mesh}
     else:
         shards, fit_kwargs = args.shards, {"n_shards": args.shards}
@@ -109,7 +115,7 @@ def main() -> None:
     print(f"[train] {rec.windows.shape[0]} windows over {shards} map "
           f"shards -> union forest of {n_trees} trees "
           f"in {time.time() - t0:.1f}s "
-          f"({'shard_map mesh' if args.devices > 0 else 'vmap emulation'})")
+          f"({'shard_map mesh' if args.devices > 0 else 'emulation'})")
 
     # ---- freeze + round-trip through the checkpoint store ---------------
     save_dir = args.save_dir or tempfile.mkdtemp(prefix="seizure_ckpt_")

@@ -48,7 +48,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import store as ckpt_store
 from repro.core import rotation_forest as rf
@@ -666,6 +666,15 @@ class SeizureEngine:
             self._state_sharding = None
             self._program_sharding = None
         else:
+            # The step declares shardings only at its jit boundary, so it
+            # runs on an Auto-axes view of the caller's devices (an
+            # Explicit-axes mesh, ``jax.make_mesh``'s default, would type
+            # every intermediate's sharding).
+            mesh = Mesh(
+                mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names),
+            )
+            self.mesh = mesh
             if max_batch % mesh.shape["data"] != 0:
                 raise ValueError(
                     f"max_batch={max_batch} not divisible by mesh "
@@ -683,8 +692,8 @@ class SeizureEngine:
                 forest_ops.PackedForest(proj=repl, thr=repl, leaf_probs=repl),
                 repl, repl,
             )
-            # Bind the static config via partial: pjit (jax 0.4) rejects
-            # kwargs once in_shardings is given.
+            # Bind the static config via partial: jit rejects kwargs once
+            # in_shardings is given.
             statics = dict(cfg=program.cfg, use_pallas=use_forest_kernel)
             jit_step = jax.jit(
                 functools.partial(step_fn, **statics),

@@ -1,4 +1,4 @@
-"""Shared test infrastructure: jax compatibility shims, hypothesis CI
+"""Shared test infrastructure: hypothesis CI
 profiles, and the seam-oracle fixtures every streaming-scoring suite
 builds on (one synthetic stream + one trained program per test session
 instead of each module rolling its own).
@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import AbstractMesh
 
 from repro.core import rotation_forest as rf
 from repro.serving import api
@@ -42,15 +41,6 @@ try:
     settings.load_profile(os.environ.get("REPRO_HYPOTHESIS_PROFILE", "ci"))
 except ImportError:  # CI installs hypothesis; local runs may lack it
     pass
-
-
-def abstract_mesh(sizes: tuple[int, ...], names: tuple[str, ...]) -> AbstractMesh:
-    """AbstractMesh across jax versions: >= 0.5 takes (sizes, names);
-    0.4 takes a single tuple of (name, size) pairs."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
 
 
 # ---------------------------------------------------------------------------

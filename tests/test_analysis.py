@@ -100,7 +100,7 @@ class TestSeededViolations:
             return x.astype(jnp.float64)
 
         entry = EntrySpec(name="bad.f64", fn=bad, args=(_sds((4,)),))
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             assert "float64-leak" in _rules_fired(entry)
 
     def test_weak_type_carry_fires(self):

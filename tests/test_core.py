@@ -9,6 +9,7 @@ import pytest
 from repro.core import decision_tree as dt
 from repro.core import ensemble, mapreduce as mr, pca
 from repro.core import rotation_forest as rf
+from repro.launch.mesh import make_data_mesh
 
 
 @pytest.fixture(scope="module")
@@ -250,7 +251,7 @@ class TestMapReduce:
         x = jnp.arange(128.0).reshape(64, 2)
         job = mr.MapReduce(lambda s: jnp.sum(s, axis=0), mr.reduce_sum)
         local = job.run_local(4, x)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         on_mesh = job.run(mesh, x)
         np.testing.assert_allclose(np.asarray(local), np.asarray(on_mesh), rtol=1e-6)
         np.testing.assert_allclose(np.asarray(local), np.asarray(x.sum(0)), rtol=1e-6)

@@ -14,6 +14,7 @@ import pytest
 
 from repro.core import forest_trainer as ft
 from repro.core import rotation_forest as rf
+from repro.launch.mesh import make_data_mesh
 from repro.signal import eeg_data, pipeline
 
 
@@ -37,7 +38,7 @@ CFG = rf.RotationForestConfig(
 class TestFitMapreduce:
     def test_mesh_equals_local_single_shard(self, blobs):
         x, y = blobs
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         on_mesh = ft.fit_mapreduce(jax.random.PRNGKey(5), x, y, CFG, mesh=mesh)
         local = ft.fit_mapreduce(jax.random.PRNGKey(5), x, y, CFG, n_shards=1)
         for a, b in zip(jax.tree.leaves(on_mesh), jax.tree.leaves(local)):
@@ -77,7 +78,8 @@ class TestFitMapreduce:
             cfg = rf.RotationForestConfig(
                 n_trees=4, n_subsets=3, depth=3, n_classes=2, n_bins=8
             )
-            mesh = jax.make_mesh((2,), ("data",))
+            from repro.launch.mesh import make_data_mesh
+            mesh = make_data_mesh(2)
             res = ft.fit_mapreduce(jax.random.PRNGKey(5), x, y, cfg, mesh=mesh)
             for leaf in jax.tree.leaves(res):
                 print("LEAF:" + np.asarray(leaf).tobytes().hex())
@@ -142,7 +144,7 @@ class TestFitMapreduce:
 
     def test_mode_selection_is_exclusive(self, blobs):
         x, y = blobs
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         with pytest.raises(ValueError, match="exactly one"):
             ft.fit_mapreduce(jax.random.PRNGKey(0), x, y, CFG)
         with pytest.raises(ValueError, match="exactly one"):

@@ -6,11 +6,15 @@ into freed slots mid-flight without draining the in-flight batch, and
 
 from __future__ import annotations
 
+import importlib.util
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_data_mesh
 from repro.serving import api
 from repro.signal import eeg_data, pipeline
 
@@ -246,6 +250,33 @@ class TestEngineOracle:
         assert res.window_preds.shape[0] == timeline.windows.shape[0]
         assert float(res.lead_time_minutes) > 0
 
+    def test_fleet_matches_plain_reference(self, program, fitted, small_cfg):
+        """``chip_smoke.py``'s check at test size: a fleet larger than the
+        slot count pushes held-out timelines in chunk-unaligned pieces of
+        different sizes (multi-chunk backlogs, eviction churn) and every
+        session's window predictions, chunk votes and alarms equal
+        ``predict_windows -> chunk_predictions -> alarm_state``. The
+        engine shape (B=1, D=1) is one this module's other tests
+        compile."""
+        smoke = _chip_smoke()
+        sizes = smoke.Sizes(sessions=2, max_batch=1, replay_depth=1)
+        fleet = smoke.fleet_timelines(0, sizes)
+        _, events, _ = smoke.serve(program, fleet, sizes)
+        served = smoke.served_by_session(events, len(fleet))
+        ref = smoke.reference(fitted, fleet, small_cfg)
+        disagree, total, alarmed = smoke.compare_with_reference(served, ref)
+        assert total == len(fleet) * (fleet[0].shape[0] // PER) * PER
+        assert disagree == 0
+        assert alarmed == len(fleet)
+
+
+def _chip_smoke():
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
 
 # ---------------------------------------------------------------------------
 # Continuous-batching scheduling
@@ -305,12 +336,27 @@ class TestContinuousScheduling:
 
     def test_mesh_engine_matches_unsharded(self, program, chunk_pool):
         quiet, pre = chunk_pool
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         results = []
         for kwargs in ({}, {"mesh": mesh}):
             engine = api.SeizureEngine(program, max_batch=2, **kwargs)
             s = engine.open_session(0)
             s.push(np.concatenate([quiet, pre, pre, pre]))
+            results.append(
+                [(e.chunk_pred, e.alarm) for e in scored_events(engine.poll())]
+            )
+        assert results[0] == results[1]
+
+    def test_explicit_axes_mesh_is_served(self, program, chunk_pool):
+        # jax.make_mesh's default Explicit axes: the engine serves them on
+        # an Auto-axes view of the same devices.
+        quiet, pre = chunk_pool
+        mesh = jax.make_mesh((1,), ("data",))
+        assert mesh.axis_types == (jax.sharding.AxisType.Explicit,)
+        results = []
+        for kwargs in ({}, {"mesh": mesh}):
+            engine = api.SeizureEngine(program, max_batch=2, **kwargs)
+            engine.open_session(0).push(np.concatenate([pre, pre, quiet]))
             results.append(
                 [(e.chunk_pred, e.alarm) for e in scored_events(engine.poll())]
             )

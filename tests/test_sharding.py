@@ -7,9 +7,8 @@ from __future__ import annotations
 
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from conftest import abstract_mesh
 from repro.configs import ARCH_NAMES, INPUT_SHAPES, get_config, shape_applicable
 from repro.data.synthetic import batch_specs
 from repro.models import build, for_shape
@@ -18,8 +17,8 @@ from repro.sharding import rules
 
 def _mesh(multi_pod=False):
     if multi_pod:
-        return abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _check_tree(mesh, shapes, specs):

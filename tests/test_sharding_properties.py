@@ -17,15 +17,14 @@ import pytest
 
 pytest.importorskip("hypothesis")  # property tests need hypothesis; CI installs it
 from hypothesis import given, strategies as st
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from conftest import abstract_mesh
 
 from repro.configs import get_config
 from repro.models import build
 from repro.sharding import rules
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 def _assert_valid(shapes, specs):

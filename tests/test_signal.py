@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import rotation_forest as rf
+from repro.launch.mesh import make_data_mesh
 from repro.signal import eeg_data, features, mspca, pipeline, wavelet
 
 
@@ -339,7 +340,7 @@ class TestPipeline:
             jax.random.PRNGKey(5), jnp.asarray(3), eeg_data.INTERICTAL, 8
         )
         serial = pipeline.process_windows(wins, small_cfg._replace(denoise=False))
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_data_mesh(1)
         cfgn = small_cfg._replace(denoise=False)
         rec = eeg_data.Recording(windows=wins, labels=jnp.zeros((8,), jnp.int32))
         dist = pipeline.process_recording_mapreduce(mesh, rec, cfgn)

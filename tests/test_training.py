@@ -10,6 +10,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.data.synthetic import make_batch
+from repro.launch.mesh import make_data_mesh
 from repro.models import build
 from repro.optim import AdamWConfig, adamw, cosine_warmup, linear_warmup
 from repro.optim.adamw import global_norm
@@ -76,7 +77,7 @@ def test_ensemble_members_diverge_and_vote():
     sync); vote-reduced predictions still well-formed."""
     cfg, model, opt, _ = _setup("xlstm-1.3b")
     n = 2
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     states = ensemble_init(model, opt, jax.random.PRNGKey(1), n)
     step = jax.jit(make_ensemble_train_step(model, opt, mesh, n))
     batch = make_batch(cfg, InputShape("t", 32, 4, "train"), seed=5)
