@@ -111,10 +111,11 @@ def global_moments(feats, combine) -> tuple[jax.Array, jax.Array]:
     ``signal.features.normalize`` (biased std + 1e-6 floor) to f32
     rounding.
     """
-    count, total = combine(_moment_partials, feats)
-    mean = total / count
-    centered_sq = combine(lambda f: _moment_partials(f, mean), feats)
-    return mean, jnp.sqrt(centered_sq / count) + 1e-6
+    with jax.named_scope("moments"):
+        count, total = combine(_moment_partials, feats)
+        mean = total / count
+        centered_sq = combine(lambda f: _moment_partials(f, mean), feats)
+        return mean, jnp.sqrt(centered_sq / count) + 1e-6
 
 
 def _shard_trees(n_trees: int, n_shards: int) -> int:
@@ -172,8 +173,9 @@ def fit_mapreduce(
     )
 
     def featurize(x_s):
-        feats = feature_fn(x_s) if feature_fn is not None else x_s
-        return feats.astype(jnp.float32)
+        with jax.named_scope("featurize"):
+            feats = feature_fn(x_s) if feature_fn is not None else x_s
+            return feats.astype(jnp.float32)
 
     def fit_shard(shard, normed, y_s):
         return rf.fit(
@@ -193,9 +195,10 @@ def fit_mapreduce(
                 jax.lax.axis_index(axis_name), (feats - mean) / std, y_s
             )
             # The reduce: union of sub-forests, replicated on every shard.
+            with jax.named_scope("gather"):
+                forest = mr.reduce_concat(sub, axis_name)
             return DistributedFitResult(
-                forest=mr.reduce_concat(sub, axis_name),
-                feat_mean=mean, feat_std=std,
+                forest=forest, feat_mean=mean, feat_std=std,
             )
 
         fn = jax.shard_map(

@@ -50,13 +50,15 @@ def _eig_sorted(cov: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Descending-eigenvalue eigendecomposition with the framework's
     deterministic sign convention (largest-|.| entry of each component
     positive, so fits are reproducible across backends)."""
-    # eigh returns ascending eigenvalues; flip to descending.
-    evals, evecs = jnp.linalg.eigh(cov)
-    order = jnp.argsort(-evals)
-    evals = jnp.take(evals, order)
-    evecs = jnp.take(evecs, order, axis=1)
-    signs = jnp.sign(evecs[jnp.argmax(jnp.abs(evecs), axis=0), jnp.arange(evecs.shape[1])])
-    evecs = evecs * jnp.where(signs == 0, 1.0, signs)[None, :]
+    with jax.named_scope("eigh"):
+        # eigh returns ascending eigenvalues; flip to descending.
+        evals, evecs = jnp.linalg.eigh(cov)
+        order = jnp.argsort(-evals)
+        evals = jnp.take(evals, order)
+        evecs = jnp.take(evecs, order, axis=1)
+        signs = jnp.sign(evecs[jnp.argmax(jnp.abs(evecs), axis=0),
+                               jnp.arange(evecs.shape[1])])
+        evecs = evecs * jnp.where(signs == 0, 1.0, signs)[None, :]
     return evals, evecs
 
 

@@ -155,15 +155,17 @@ def fit(key: jax.Array, x: jax.Array, y: jax.Array, cfg: RotationForestConfig) -
     x = _pad_features(x.astype(jnp.float32), cfg.n_subsets)
     y = y.astype(jnp.int32)
     keys = jax.random.split(key, cfg.n_trees)
-    rots, xbs, ws, edges = jax.vmap(
-        lambda k: _prepare_one(k, x, y, cfg)
-    )(keys)
-    trees = dt.fit_forest_binned(
-        xbs, y, ws,
-        depth=cfg.depth, n_classes=cfg.n_classes, n_bins=cfg.n_bins,
-        min_samples=cfg.min_samples, bin_edges=edges,
-        use_kernel=cfg.use_hist_kernel,
-    )
+    with jax.named_scope("rotate"):
+        rots, xbs, ws, edges = jax.vmap(
+            lambda k: _prepare_one(k, x, y, cfg)
+        )(keys)
+    with jax.named_scope("grow"):
+        trees = dt.fit_forest_binned(
+            xbs, y, ws,
+            depth=cfg.depth, n_classes=cfg.n_classes, n_bins=cfg.n_bins,
+            min_samples=cfg.min_samples, bin_edges=edges,
+            use_kernel=cfg.use_hist_kernel,
+        )
     return RotationForestParams(rotation=rots, trees=trees)
 
 

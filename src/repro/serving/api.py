@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.checkpoint import store as ckpt_store
 from repro.core import rotation_forest as rf
 from repro.kernels.forest import ops as forest_ops
@@ -248,14 +249,15 @@ def _vote_chunks(feats, packed, feat_mean, feat_std, *, use_pallas):
     implementation both the stateless score path and the engine's
     replay-scan body share."""
     b, w, f = feats.shape
-    normed, _, _ = features.normalize(feats.reshape(b * w, f),
-                                      feat_mean, feat_std)
-    probs = forest_ops.forest_predict_proba(
-        packed, normed, use_pallas=use_pallas
-    )
-    preds = jnp.argmax(probs, axis=-1).reshape(b, w).astype(jnp.int32)
-    frac = jnp.mean(preds.astype(jnp.float32), axis=1)
-    votes = (frac > 0.5).astype(jnp.int32)
+    with jax.named_scope("vote"):
+        normed, _, _ = features.normalize(feats.reshape(b * w, f),
+                                          feat_mean, feat_std)
+        probs = forest_ops.forest_predict_proba(
+            packed, normed, use_pallas=use_pallas
+        )
+        preds = jnp.argmax(probs, axis=-1).reshape(b, w).astype(jnp.int32)
+        frac = jnp.mean(preds.astype(jnp.float32), axis=1)
+        votes = (frac > 0.5).astype(jnp.int32)
     return votes, frac, preds
 
 
@@ -383,10 +385,11 @@ def _engine_step_megabatch(state, chunks, active, packed, feat_mean,
         )
         return (rings, pos, alarm), alarm
 
-    (rings, ring_pos, alarm), alarm_seq = jax.lax.scan(
-        ring_body, (state.rings, state.ring_pos, state.alarm),
-        (votes.T, active.T),
-    )
+    with jax.named_scope("ring"):
+        (rings, ring_pos, alarm), alarm_seq = jax.lax.scan(
+            ring_body, (state.rings, state.ring_pos, state.alarm),
+            (votes.T, active.T),
+        )
     new_state = EngineState(
         rings=rings, ring_pos=ring_pos, alarm=alarm,
         fe_boundary=fe.boundary, fe_phase=fe.phase,
@@ -616,6 +619,31 @@ class SeizureEngine:
     payload rides the same evict/admit splice as the alarm ring, so
     eviction churn cannot perturb the numerics (property-tested in
     tests/test_engine_properties.py).
+
+    Counters, plain ints (one float) that only grow, for operators:
+
+      steps           jitted step invocations (restored from a snapshot).
+      chunks_scored   chunks the steps took from session queues.
+      slot_positions  chunk positions the steps computed, max_batch x
+                      replay_depth each: chunks_scored / slot_positions
+                      is the share of the step that was not padding.
+      h2d_bytes       bytes copied host -> device: each step's batch and
+                      mask, and each admission's saved stream state.
+      d2h_bytes       bytes copied device -> host: each step's votes,
+                      fractions, alarms and window predictions, and the
+                      state that evictions and frontend syncs pull.
+      evictions       drained sessions pulled out of a slot.
+      admissions      session states spliced into a slot (``reset_alarm``
+                      re-splices a resident one).
+      queue_wait_s    sum over the chunks taken of (step start - the
+                      chunk's enqueue time), on the engine's ``clock``.
+
+    All but ``steps`` count from construction or restore. While a
+    ``jax.profiler`` trace runs, ``poll`` also writes the host spans
+    ``seizure.fill`` (``_fill_slots``) and, per step,
+    ``seizure.assemble``, ``seizure.put``, ``seizure.dispatch``,
+    ``seizure.readback`` and ``seizure.events``, with the counters' share
+    of that span as its args (``repro.obs``).
     """
 
     def __init__(
@@ -646,6 +674,13 @@ class SeizureEngine:
         # when cfg.overlap > 0; a single carried-but-unused window else).
         self.fe_width = frontend.boundary_width(program.cfg.overlap)
         self.steps = 0  # jitted step invocations (scheduling observability)
+        self.chunks_scored = 0
+        self.slot_positions = 0
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
+        self.evictions = 0
+        self.admissions = 0
+        self.queue_wait_s = 0.0
         self.program_version = 0  # bumped by each swap_program
         self._clock = clock
 
@@ -846,6 +881,7 @@ class SeizureEngine:
         boundary, phase = jax.device_get((
             self._state.fe_boundary, self._state.fe_phase
         ))
+        self.d2h_bytes += boundary.nbytes + phase.nbytes
         session.fe_boundary = np.asarray(boundary[slot])
         session.fe_phase = int(phase[slot])
 
@@ -861,6 +897,9 @@ class SeizureEngine:
             self._state.fe_boundary,
             self._state.fe_phase,
         ))
+        self.d2h_bytes += sum(a.nbytes for a in (ring, pos, alarm, boundary,
+                                                 phase))
+        self.evictions += 1
         session.ring = np.asarray(ring[slot])
         session.ring_pos = int(pos[slot])
         session.alarm = int(alarm[slot])
@@ -877,26 +916,40 @@ class SeizureEngine:
         # jax.transfer_guard("disallow"), which turns any IMPLICIT
         # transfer into an error -- every intentional crossing is spelled
         # out (tests/conftest.py `device_transfer_sanitizer`).
-        self._state = self._splice(
-            self._state,
-            jax.device_put(np.int32(slot)),
-            jax.device_put(np.asarray(session.ring, np.int32)),
-            jax.device_put(np.int32(session.ring_pos)),
-            jax.device_put(np.int32(session.alarm)),
-            jax.device_put(np.asarray(session.fe_boundary, np.float32)),
-            jax.device_put(np.int32(session.fe_phase)),
+        saved = (
+            np.int32(slot),
+            np.asarray(session.ring, np.int32),
+            np.int32(session.ring_pos),
+            np.int32(session.alarm),
+            np.asarray(session.fe_boundary, np.float32),
+            np.int32(session.fe_phase),
         )
+        self._state = self._splice(
+            self._state, *(jax.device_put(a) for a in saved)
+        )
+        self.h2d_bytes += sum(a.nbytes for a in saved)
+        self.admissions += 1
         session.slot = slot
         session.queued = False
         self._slots[slot] = session
 
     def _fill_slots(self) -> None:
-        for i in range(self.max_batch):
-            occupant = self._slots[i]
-            if occupant is not None and not occupant.chunks and self._waiting:
-                self._evict(i)  # refill mid-flight: drained session yields
-            if self._slots[i] is None and self._waiting:
-                self._admit(i, self._waiting.popleft())
+        before = (self.evictions, self.admissions, self.d2h_bytes,
+                  self.h2d_bytes)
+        with obs.span("fill") as span:
+            for i in range(self.max_batch):
+                occupant = self._slots[i]
+                if (occupant is not None and not occupant.chunks
+                        and self._waiting):
+                    self._evict(i)  # refill mid-flight: drained session yields
+                if self._slots[i] is None and self._waiting:
+                    self._admit(i, self._waiting.popleft())
+            span.set_metadata(
+                evictions=self.evictions - before[0],
+                admissions=self.admissions - before[1],
+                d2h_bytes=self.d2h_bytes - before[2],
+                h2d_bytes=self.h2d_bytes - before[3],
+            )
 
     # -- serving -------------------------------------------------------------
 
@@ -945,56 +998,77 @@ class SeizureEngine:
         return events
 
     def _step_once(self, active: list[int]) -> list:
-        # Fixed D: every step pads the backlog axis to ``replay_depth``,
-        # so steady-state and replay traffic run ONE compiled program.
-        depth = self.replay_depth
-        batch = np.zeros(
-            (self.max_batch, depth, self.chunk_windows, eeg_data.N_CHANNELS,
-             eeg_data.WINDOW),
-            np.float32,
-        )
-        mask = np.zeros((self.max_batch, depth), np.int32)
-        popped: dict[int, int] = {}
-        for i in active:
-            session = self._slots[i]
-            take = min(depth, len(session.chunks))
-            for j in range(take):
-                _, batch[i, j] = session.chunks.popleft()
-                mask[i, j] = 1
-            popped[i] = take
+        start = self._clock()
+        with obs.span("assemble") as span:
+            # Fixed D: every step pads the backlog axis to ``replay_depth``,
+            # so steady-state and replay traffic run ONE compiled program.
+            depth = self.replay_depth
+            batch = np.zeros(
+                (self.max_batch, depth, self.chunk_windows,
+                 eeg_data.N_CHANNELS, eeg_data.WINDOW),
+                np.float32,
+            )
+            mask = np.zeros((self.max_batch, depth), np.int32)
+            popped: dict[int, int] = {}
+            wait = 0.0
+            for i in active:
+                session = self._slots[i]
+                take = min(depth, len(session.chunks))
+                for j in range(take):
+                    enqueued, batch[i, j] = session.chunks.popleft()
+                    wait += start - enqueued
+                    mask[i, j] = 1
+                popped[i] = take
+            taken = sum(popped.values())
+            span.set_metadata(chunks=taken, queue_wait_s=wait)
+        self.chunks_scored += taken
+        self.slot_positions += self.max_batch * depth
+        self.queue_wait_s += wait
         program = self.program
-        # device_put, not jnp.asarray: the batch crossing is an EXPLICIT
-        # transfer, legal under jax.transfer_guard("disallow").
-        self._state, votes, frac, alarm, preds = self._step(
-            self._state, jax.device_put(batch), jax.device_put(mask),
-            program.packed, program.feat_mean, program.feat_std,
-            cfg=program.cfg, use_pallas=self.use_forest_kernel,
-        )
+        sent = batch.nbytes + mask.nbytes
+        with obs.span("put", bytes=sent):
+            # device_put, not jnp.asarray: the batch crossing is an
+            # EXPLICIT transfer, legal under jax.transfer_guard("disallow").
+            batch, mask = jax.device_put(batch), jax.device_put(mask)
+        self.h2d_bytes += sent
+        with obs.span("dispatch"):
+            self._state, votes, frac, alarm, preds = self._step(
+                self._state, batch, mask,
+                program.packed, program.feat_mean, program.feat_std,
+                cfg=program.cfg, use_pallas=self.use_forest_kernel,
+            )
         self.steps += 1
-        votes, frac, alarm, preds = jax.device_get((votes, frac, alarm, preds))
+        with obs.span("readback") as span:
+            votes, frac, alarm, preds = jax.device_get(
+                (votes, frac, alarm, preds)
+            )
+            got = votes.nbytes + frac.nbytes + alarm.nbytes + preds.nbytes
+            span.set_metadata(bytes=got)
+        self.d2h_bytes += got
         events: list = []
-        for i in active:
-            session = self._slots[i]
-            for j in range(popped[i]):
-                prev_alarm, session.alarm = session.alarm, int(alarm[i, j])
-                events.append(ChunkScored(
-                    patient_id=session.patient_id,
-                    chunk_index=session.chunk_seq,
-                    chunk_pred=int(votes[i, j]),
-                    preictal_frac=float(frac[i, j]),
-                    alarm=session.alarm,
-                    window_preds=np.asarray(preds[i, j]),
-                    program_version=self.program_version,
-                ))
-                if session.alarm > prev_alarm:
-                    events.append(
-                        AlarmRaised(session.patient_id, session.chunk_seq)
-                    )
-                elif session.alarm < prev_alarm:
-                    events.append(
-                        AlarmCleared(session.patient_id, session.chunk_seq)
-                    )
-                session.chunk_seq += 1
+        with obs.span("events"):
+            for i in active:
+                session = self._slots[i]
+                for j in range(popped[i]):
+                    prev_alarm, session.alarm = session.alarm, int(alarm[i, j])
+                    events.append(ChunkScored(
+                        patient_id=session.patient_id,
+                        chunk_index=session.chunk_seq,
+                        chunk_pred=int(votes[i, j]),
+                        preictal_frac=float(frac[i, j]),
+                        alarm=session.alarm,
+                        window_preds=np.asarray(preds[i, j]),
+                        program_version=self.program_version,
+                    ))
+                    if session.alarm > prev_alarm:
+                        events.append(
+                            AlarmRaised(session.patient_id, session.chunk_seq)
+                        )
+                    elif session.alarm < prev_alarm:
+                        events.append(
+                            AlarmCleared(session.patient_id, session.chunk_seq)
+                        )
+                    session.chunk_seq += 1
         return events
 
     def score_chunks(self, chunks) -> tuple[jax.Array, jax.Array, jax.Array]:

@@ -53,11 +53,12 @@ def wpd_features(
     gather + matmul analysis formulation (``wavelet.analysis_step``'s
     ``reference`` path).
     """
-    nodes = wavelet.wpd(
-        windows, level, wavelet_name, use_kernel=use_kernel,
-        reference=reference_kernels,
-    )
-    feats = node_features(nodes)  # (..., C, 2**level, 6)
+    with jax.named_scope("wpd"):
+        nodes = wavelet.wpd(
+            windows, level, wavelet_name, use_kernel=use_kernel,
+            reference=reference_kernels,
+        )
+        feats = node_features(nodes)  # (..., C, 2**level, 6)
     lead = windows.shape[:-2]
     return feats.reshape(lead + (-1,))
 

@@ -205,20 +205,22 @@ def chunk_features(
             halos = jnp.concatenate(
                 [halo[None].astype(jnp.float32), mats[:-1, per - h:]]
             )
-            den = jax.vmap(
-                lambda m, hl: mspca.denoise_windows(
-                    m, level=cfg.mspca_level, wavelet_name=cfg.wavelet,
-                    halo=hl,
-                    reference_kernels=cfg.reference_kernels,
-                )
-            )(mats, halos)
+            with jax.named_scope("mspca"):
+                den = jax.vmap(
+                    lambda m, hl: mspca.denoise_windows(
+                        m, level=cfg.mspca_level, wavelet_name=cfg.wavelet,
+                        halo=hl,
+                        reference_kernels=cfg.reference_kernels,
+                    )
+                )(mats, halos)
         else:
-            den = jax.vmap(
-                lambda m: mspca.denoise_windows(
-                    m, level=cfg.mspca_level, wavelet_name=cfg.wavelet,
-                    reference_kernels=cfg.reference_kernels,
-                )
-            )(mats)
+            with jax.named_scope("mspca"):
+                den = jax.vmap(
+                    lambda m: mspca.denoise_windows(
+                        m, level=cfg.mspca_level, wavelet_name=cfg.wavelet,
+                        reference_kernels=cfg.reference_kernels,
+                    )
+                )(mats)
         chunk_windows = den.reshape(n_mat * per, c, n)[:w]
     return features.wpd_features(
         chunk_windows, level=cfg.wpd_level, wavelet_name=cfg.wavelet,

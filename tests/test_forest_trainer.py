@@ -3,6 +3,8 @@ the union-reduce algebra, and the signal-level mesh-aware fit path."""
 
 from __future__ import annotations
 
+import pathlib
+import re
 import subprocess
 import sys
 import textwrap
@@ -156,6 +158,31 @@ class TestFitMapreduce:
         x, y = blobs
         with pytest.raises(ValueError, match="shard evenly"):
             ft.fit_mapreduce(jax.random.PRNGKey(0), x, y, CFG, n_shards=7)
+
+
+    def test_fit_lowering_carries_the_stage_scopes(self, small_cfg):
+        """The mesh-free fit names its stages for a profiler trace
+        (``jax.named_scope``): the per-shard featurization with the MSPCA,
+        eigh and WPD inside it, the global moments, the rotations and the
+        grower. Lowered only, not compiled."""
+        sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]
+                               / "benchmarks" / "chip"))
+        from chipbench import program_trace
+
+        per = eeg_data.WINDOWS_PER_MATRIX
+        text = jax.jit(lambda k, x, y: ft.fit_mapreduce(
+            k, x, y, small_cfg.forest, n_shards=2,
+            feature_fn=lambda w: pipeline.process_windows(w, small_cfg),
+        )).lower(
+            jax.random.PRNGKey(0),
+            jax.ShapeDtypeStruct((4 * per, eeg_data.N_CHANNELS,
+                                  eeg_data.WINDOW), jnp.float32),
+            jax.ShapeDtypeStruct((4 * per,), jnp.int32),
+        ).as_text(debug_info=True)
+        found = set().union(*map(program_trace.segments,
+                                 re.findall(r'loc\("([^"]+)"', text)))
+        assert {"featurize", "mspca", "eigh", "wpd", "moments", "rotate",
+                "grow"} <= found
 
 
 class TestMergeAlgebra:

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import importlib.util
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -361,6 +362,104 @@ class TestContinuousScheduling:
                 [(e.chunk_pred, e.alarm) for e in scored_events(engine.poll())]
             )
         assert results[0] == results[1]
+
+
+# ---------------------------------------------------------------------------
+# Observability: counters, host spans in a profiler trace, device scopes
+# ---------------------------------------------------------------------------
+
+def _midflight_engine(program, quiet, clock):
+    """``test_midflight_refill_no_drain_barrier``'s traffic: 2 slots,
+    sessions of 3, 1 and 2 chunks, all pushed at t=0."""
+    engine = api.SeizureEngine(program, max_batch=2, clock=clock)
+    for pid, n in ((1, 3), (2, 1), (3, 2)):
+        engine.open_session(pid).push(np.concatenate([quiet] * n))
+    return engine
+
+
+def _bytes_per(engine):
+    """(step batch + mask, step read-back, admission, eviction) bytes."""
+    b, d, m = engine.max_batch, engine.replay_depth, engine.alarm_m
+    window = eeg_data.N_CHANNELS * eeg_data.WINDOW * 4  # one f32 window
+    boundary = engine.fe_width * window
+    slots, w = b * d, engine.chunk_windows
+    return (slots * w * window + slots * 4,   # batch, mask
+            slots * 3 * 4 + slots * w * 4,    # votes, frac, alarm; preds
+            4 * 4 + m * 4 + boundary,         # slot, pos, alarm, phase; ring
+            b * (m * 4 + 3 * 4 + boundary))   # every slot's state
+
+
+def _program_trace():
+    """The chip benchmark's reader of the program's trace marks."""
+    import sys
+
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]
+                           / "benchmarks" / "chip"))
+    from chipbench import program_trace
+
+    return program_trace
+
+
+class TestObservability:
+    def test_counters_match_the_hand_count(self, program, chunk_pool):
+        quiet, _ = chunk_pool
+        now = [0.0]
+        engine = _midflight_engine(program, quiet, lambda: now[0])
+        now[0] = 10.0
+        engine.poll()
+        step, readback, admission, eviction = _bytes_per(engine)
+        # Slot 1's one-chunk session drains after step 1 and yields to
+        # the queued one; nothing is evicted once the queue is empty.
+        assert (engine.steps, engine.chunks_scored, engine.slot_positions,
+                engine.evictions, engine.admissions) == (3, 6, 6, 1, 3)
+        assert engine.h2d_bytes == 3 * step + 3 * admission
+        assert engine.d2h_bytes == 3 * readback + eviction
+        assert engine.queue_wait_s == 6 * 10.0
+
+    def test_poll_writes_program_spans_into_a_profiler_trace(
+        self, program, chunk_pool, tmp_path
+    ):
+        program_trace = _program_trace()
+        quiet, _ = chunk_pool
+        engine = _midflight_engine(program, quiet, lambda: 0.0)
+        step, readback, admission, eviction = _bytes_per(engine)
+        with jax.profiler.trace(str(tmp_path)):
+            with jax.profiler.TraceAnnotation("bench.poll"):
+                engine.poll()
+        host = sorted(program_trace.load(str(tmp_path))["host"],
+                      key=lambda h: h[1])
+        caller, spans = host[0], host[1:]
+        assert caller[0] == "bench.poll"
+        per_step = ["seizure.fill", "seizure.assemble", "seizure.put",
+                    "seizure.dispatch", "seizure.readback", "seizure.events"]
+        assert [h[0] for h in spans] == per_step * 3 + ["seizure.fill"]
+        assert all(caller[1] <= s and s + d <= caller[1] + caller[2]
+                   for _, s, d, _ in spans)
+        ends = [s + d for _, s, d, _ in spans]
+        assert all(e <= s for e, (_, s, _, _) in zip(ends, spans[1:]))
+        args = [h[3] for h in spans]
+        assert args[0] == {"evictions": 0, "admissions": 2, "d2h_bytes": 0,
+                           "h2d_bytes": 2 * admission}
+        assert args[6] == {"evictions": 1, "admissions": 1,
+                           "d2h_bytes": eviction, "h2d_bytes": admission}
+        assert [a["chunks"] for a in args[1::6]] == [2, 2, 2]
+        assert [a["bytes"] for a in args[2::6]] == [step] * 3
+        assert [a["bytes"] for a in args[4::6]] == [readback] * 3
+        assert args[3] == args[5] == {}
+
+    def test_step_lowering_carries_the_stage_scopes(self, program):
+        program_trace = _program_trace()
+        sd = jax.ShapeDtypeStruct
+        chunks = sd((2, 1, PER, eeg_data.N_CHANNELS, eeg_data.WINDOW),
+                    jnp.float32)
+        text = api._jit_engine_step_megabatch.lower(
+            api.init_state(2, program.cfg.alarm_m), chunks,
+            sd((2, 1), jnp.int32), program.packed, program.feat_mean,
+            program.feat_std, cfg=program.cfg, use_pallas=False,
+        ).as_text(debug_info=True)
+        found = set().union(*map(program_trace.segments,
+                                 re.findall(r'loc\("([^"]+)"', text)))
+        assert {"mspca", "eigh", "wpd", "vote", "ring"} <= found
 
 
 # ---------------------------------------------------------------------------
