@@ -93,6 +93,27 @@ def fit_T(xT: jax.Array) -> PCAState:
     return PCAState(components=evecs, mean=mean, variances=jnp.maximum(evals, 0.0))
 
 
+def fit_gram_T(xT: jax.Array) -> PCAState:
+    """Gram-side twin of ``fit_T`` for ``xT`` of shape (F, N) with fewer
+    samples than features (N < F). The centred data has rank at most
+    N - 1, so the (F, F) covariance and the (N, N) Gram matrix
+    ``xcᵀ xc / (N - 1)`` share their nonzero eigenvalues, and only the
+    smaller one is decomposed. ``components`` is then (N, N): its
+    columns are directions in SAMPLE space (the right singular vectors
+    of the centred data), which ``reconstruct_gram_T`` pairs with;
+    ``variances`` holds the N leading eigenvalues (the F - N left out
+    are zero)."""
+    xT = xT.astype(jnp.float32)
+    mean = jnp.mean(xT, axis=1)
+    xc = xT - mean[:, None]
+    gram = jnp.einsum(
+        "pn,pm->nm", xc, xc, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    ) / jnp.maximum(xT.shape[1] - 1, 1)
+    evals, evecs = _eig_sorted(gram)
+    return PCAState(components=evecs, mean=mean, variances=jnp.maximum(evals, 0.0))
+
+
 def transform(state: PCAState, x: jax.Array, n_components: int | None = None) -> jax.Array:
     comps = state.components if n_components is None else state.components[:, :n_components]
     return (x - state.mean) @ comps
@@ -149,6 +170,23 @@ def reconstruct_T(
     return state.components @ (scores * mask[:, None]) + state.mean[:, None]
 
 
+def reconstruct_gram_T(
+    state: PCAState, xT: jax.Array, keep: jax.Array | int
+) -> jax.Array:
+    """``reconstruct_T`` for a ``fit_gram_T`` state: (F, N) -> (F, N).
+    With xc = U S Vᵀ, the projection onto the leading k principal
+    directions is Uₖ Uₖᵀ xc = xc Vₖ Vₖᵀ, so it runs on the sample side.
+    A static Python int ``keep`` slices Vₖ; a traced count masks the
+    score columns instead."""
+    xc = xT - state.mean[:, None]
+    if isinstance(keep, int):
+        v = state.components[:, : min(keep, state.components.shape[1])]
+        return (xc @ v) @ v.T + state.mean[:, None]
+    scores = xc @ state.components  # (F, N)
+    mask = (jnp.arange(scores.shape[1]) < keep).astype(scores.dtype)
+    return (scores * mask[None, :]) @ state.components.T + state.mean[:, None]
+
+
 def n_components_for_variance(state: PCAState, frac: float = 0.95) -> jax.Array:
     """Smallest k capturing ``frac`` of total variance (traceable)."""
     total = jnp.sum(state.variances)
@@ -156,7 +194,15 @@ def n_components_for_variance(state: PCAState, frac: float = 0.95) -> jax.Array:
     return jnp.sum(cum < frac * jnp.maximum(total, 1e-12)) + 1
 
 
-def kaiser_rule(state: PCAState) -> jax.Array:
+def kaiser_rule(state: PCAState, n_features: int | None = None) -> jax.Array:
     """Number of components with eigenvalue above the mean eigenvalue --
-    the classical selection rule used by MSPCA implementations."""
-    return jnp.maximum(jnp.sum(state.variances > jnp.mean(state.variances)), 1)
+    the classical selection rule used by MSPCA implementations.
+
+    ``n_features``: the width the mean is taken over, for a
+    ``fit_gram_T`` state, whose F - N zero eigenvalues are not stored:
+    the mean stays trace / F, as the covariance side computes it."""
+    if n_features is None:
+        mean = jnp.mean(state.variances)
+    else:
+        mean = jnp.sum(state.variances) / n_features
+    return jnp.maximum(jnp.sum(state.variances > mean), 1)

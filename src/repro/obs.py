@@ -12,11 +12,12 @@ is a ``TraceMe`` that finds none and records nothing, about a
 microsecond of host time: there is no flag to turn it off.
 
 The device stages carry ``jax.named_scope`` names instead (``mspca``,
-``eigh``, ``wpd``, ``vote``, ``ring``; in training ``featurize``,
-``moments``, ``rotate``, ``grow``, ``gather``). A scope changes only the
-operations' metadata, never the compiled program: it is each
-instruction's ``op_name`` in the executable's HLO, by which the
-operations of a trace can be put down to their stage.
+``eigh``, ``dual`` around a Gram-side band, ``wpd``, ``vote``, ``ring``;
+in training ``featurize``, ``moments``, ``rotate``, ``grow``,
+``gather``). A scope changes only the operations' metadata, never the
+compiled program: it is each instruction's ``op_name`` in the
+executable's HLO, by which the operations of a trace can be put down
+to their stage.
 """
 
 from __future__ import annotations

@@ -48,7 +48,22 @@ def _pca_reconstruct_T(cT: jax.Array, keep) -> jax.Array:
     """Variable-major twin of ``_pca_reconstruct``: ``cT`` is (P, n) --
     exactly the layout ``wavelet.dwt`` hands back per scale -- so the
     fit and the projection run without the two full-matrix transposes
-    the sample-major form pays per scale."""
+    the sample-major form pays per scale.
+
+    The side of the eigendecomposition follows the band's static shape:
+    a band with fewer samples than variables (n < P: D4, D5 and A5 of
+    the paper's 2048 x 180 matrix at level 5) decomposes the n x n Gram
+    matrix instead of the P x P covariance (``pca.fit_gram_T``), under
+    the ``dual`` scope; the projection onto the leading components is
+    the same. Every other band takes the covariance side."""
+    p, n = cT.shape
+    if n < p:
+        with jax.named_scope("dual"):
+            st = pca.fit_gram_T(cT)
+            if keep == "kaiser":
+                k = pca.kaiser_rule(st, n_features=p)
+                return pca.reconstruct_gram_T(st, cT, k)
+            return pca.reconstruct_gram_T(st, cT, int(keep))
     st = pca.fit_T(cT)
     if keep == "kaiser":
         k = jnp.minimum(pca.kaiser_rule(st), cT.shape[0])
