@@ -6,22 +6,30 @@ A workload names a configuration (``configs/<config>.json``, whose
 reads). The end-to-end metrics a run reports are the
 ones ``BENCHMARK.json`` gives the cell; the per-layer metrics of a traced
 run are read by ``metrics/<name>.py``, each a ``read(ctx)`` that returns
-a number or None. Nothing here names a cell, a mix or a metric.
+a number or None. A reader's ``ctx`` holds ``spans`` and ``counters``
+(the benchmark's own, ``spans.Recorder``), ``window_s``, ``trace``
+(``tracing.reduce``: device time and the benchmark's spans) and
+``program`` (``program_trace.reduce``: the program's ``seizure.*`` spans
+with their args, and device time per ``jax.named_scope``, the scopes
+taken from the compiled HLO of what the window ran, ``hlo_text`` of the
+driver). Nothing here names a cell, a mix or a metric.
 """
 
 from __future__ import annotations
 
 import importlib.util
 import json
+import os
 import pathlib
 import shutil
 import sys
 import tempfile
 import time
+from typing import NamedTuple
 
 import jax
 
-from chipbench import compiles, serve, spans, tracing, train
+from chipbench import compiles, program_trace, serve, spans, tracing, train
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
 HOME = BENCH_DIR.relative_to(BENCH_DIR.parents[1])   # benchmarks/chip
@@ -52,6 +60,18 @@ def load_spec(root: pathlib.Path, workload: str) -> tuple[dict, dict, dict, dict
     return bench, cell, cfg, traffic
 
 
+def use_cache(root: pathlib.Path) -> None:
+    """JAX's persistent compilation cache: ``JAX_COMPILATION_CACHE_DIR``
+    where it is set, else ``.jax_cache`` in the checkout; every program
+    cached, and metadata part of the key, so that a program loaded from
+    the cache carries this commit's scopes and not an older one's."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
+
 def applies(metric: dict, workload: str) -> bool:
     return "workloads" not in metric or workload in metric["workloads"]
 
@@ -70,6 +90,73 @@ def memory_peak(devices) -> int:
                for d in devices)
 
 
+class Measured(NamedTuple):
+    window: object                 # the driver's Window
+    rec: spans.Recorder
+    counter: compiles.Counter      # what compiled inside the window
+    peak: int                      # memory_peak_bytes after the window
+    trace: tracing.Reduction | None
+    program: program_trace.Program | None
+    events: dict | None            # program_trace.load's events, scoped
+
+    def context(self) -> dict:
+        """What the per-layer metrics' readers are handed."""
+        return {"spans": {n: (self.rec.seconds[n], self.rec.calls[n])
+                          for n in self.rec.seconds},
+                "counters": self.rec.counters,
+                "window_s": self.window.seconds,
+                "trace": self.trace, "program": self.program}
+
+
+def measure(driver, state, seconds: float, seed: int, traced: bool,
+            devices: list) -> Measured:
+    """The window; traced, the profiler's trace of it reduced twice: by
+    ``tracing`` and, with the scopes of the compiled HLO of the driver's
+    program attached, by ``program_trace``."""
+    rec = spans.Recorder(traced)
+    trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if traced else None
+    reduction = prog = events = None
+    try:
+        if traced:
+            jax.profiler.start_trace(trace_dir)
+        with compiles.Counter() as counter:
+            window = driver.run_window(state, seconds, seed, rec)
+        if traced:
+            jax.profiler.stop_trace()
+            log("trace written")
+        peak = memory_peak(devices)
+        if traced:
+            reduction = tracing.reduce(tracing.load(trace_dir))
+            events = program_trace.load(trace_dir)
+    finally:
+        if trace_dir:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+    log(f"window: {window.seconds:.3f} s, counters {rec.counters}, "
+        f"in-window {counter.counts}")
+    if traced:
+        scoped = program_trace.attach_scopes(events, driver.hlo_text(state))
+        _, prog = program_trace.reduce(events)
+        coll = sorted(n for n in reduction.first.op_s
+                      if tracing.COLLECTIVE.search(n))
+        log(f"trace: {sorted(reduction.devices)}, busy "
+            f"{reduction.first.busy_s:.3f} of {reduction.window_s:.3f} s on "
+            f"the first chip; programs {reduction.first.module_calls}; "
+            f"collective ops {coll[:12]}; {scoped} operations scoped")
+    return Measured(window, rec, counter, peak, reduction, prog, events)
+
+
+def per_layer(bench: dict, workload: str, ctx: dict,
+              root: pathlib.Path = BENCH_DIR.parents[1]) -> dict:
+    """The cell's per-layer metrics that its readers find."""
+    out = {}
+    for m in bench["per_layer"]:
+        if applies(m, workload):
+            value = read_metric(m["name"], ctx, root)
+            if value is not None:
+                out[m["name"]] = value
+    return out
+
+
 def run(root: pathlib.Path, workload: str, seed: int, seconds: float,
         traced: bool, devices: list, t_start: float,
         spec: tuple | None = None, control: bool = False) -> dict:
@@ -80,32 +167,11 @@ def run(root: pathlib.Path, workload: str, seed: int, seconds: float,
     bench, cell, cfg, traffic = spec or load_spec(root, workload)
     driver = DRIVERS[cfg["kind"]]
     state = driver.setup(cfg, traffic, seed, devices, log)
-    rec = spans.Recorder(traced)
-    trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if traced else None
     setup_s = time.perf_counter() - t_start
     log(f"set-up done in {setup_s:.3f} s; window of {seconds} s")
-    try:
-        if traced:
-            jax.profiler.start_trace(trace_dir)
-        with compiles.Counter() as counter:
-            window = driver.run_window(state, seconds, seed, rec)
-        if traced:
-            jax.profiler.stop_trace()
-        peak = memory_peak(devices)
-        reduction = (tracing.reduce(tracing.load(trace_dir)) if traced
-                     else None)
-    finally:
-        if trace_dir:
-            shutil.rmtree(trace_dir, ignore_errors=True)
-    log(f"window: {window.seconds:.3f} s, counters {rec.counters}, "
-        f"in-window {counter.counts}")
-    if traced:
-        coll = sorted(n for n in reduction.first.op_s
-                      if tracing.COLLECTIVE.search(n))
-        log(f"trace: {sorted(reduction.devices)}, busy "
-            f"{reduction.first.busy_s:.3f} of {reduction.window_s:.3f} s on "
-            f"the first chip; programs {reduction.first.module_calls}; "
-            f"collective ops {coll[:12]}")
+    measured = measure(driver, state, seconds, seed, traced, devices)
+    window, counter, reduction = (measured.window, measured.counter,
+                                  measured.trace)
 
     t_check = time.perf_counter()
     numbers = driver.check(state, window, seed, control)
@@ -118,25 +184,15 @@ def run(root: pathlib.Path, workload: str, seed: int, seconds: float,
 
     units = {m["name"]: m["unit"] for m in bench["end_to_end"]
              + bench["per_layer"]}
-    metrics: dict = {}
     if traced:
-        ctx = {"spans": {n: (rec.seconds[n], rec.calls[n])
-                         for n in rec.seconds},
-               "counters": rec.counters, "window_s": window.seconds,
-               "trace": reduction}
-        for m in bench["per_layer"]:
-            if applies(m, workload):
-                value = read_metric(m["name"], ctx, root)
-                if value is not None:
-                    metrics[m["name"]] = value
+        metrics = per_layer(bench, workload, measured.context(), root)
     else:
         values = dict(driver.end_to_end(window), setup_s=setup_s)
-        for m in bench["end_to_end"]:
-            if applies(m, workload):
-                metrics[m["name"]] = values[m["name"]]
+        metrics = {e["name"]: values[e["name"]] for e in bench["end_to_end"]
+                   if applies(e, workload)}
     attempted, failed = driver.attempted_failed(window)
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
-              "count": len(devices), "memory_peak_bytes": peak}
+              "count": len(devices), "memory_peak_bytes": measured.peak}
     result = {
         "correct": all(c["value"] <= c["limit"] for c in checks.values()),
         "attempted": attempted,

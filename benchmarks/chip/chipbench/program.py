@@ -6,6 +6,34 @@ from __future__ import annotations
 
 from chipbench import reference
 
+# What the program serves where its ``PipelineConfig`` has no field for a
+# key of the configuration's ``signal`` block: the constant of
+# ``repro.signal.eeg_data`` that fixes it.
+PROGRAM_SIGNAL = {"channels": "N_CHANNELS", "window_samples": "WINDOW",
+                  "chunk_windows": "WINDOWS_PER_MATRIX",
+                  "sample_rate_hz": "FS"}
+
+
+def signal_fields(signal: dict, fields) -> dict:
+    """The ``signal`` keys to pass to the program's ``PipelineConfig``
+    (whose fields are ``fields``). A key that names a field is passed; a
+    key that names none has to equal what the program serves, else the
+    configuration is refused before anything is built or compiled."""
+    from repro.signal import eeg_data
+
+    passed = {}
+    for key, value in signal.items():
+        if key in fields:
+            passed[key] = value
+            continue
+        served = getattr(eeg_data, PROGRAM_SIGNAL.get(key, ""), None)
+        if value != served:
+            raise ValueError(
+                f"configuration key signal.{key}={value!r}: the program's "
+                f"PipelineConfig has no field {key!r}, and the program "
+                f"serves {served!r} only")
+    return passed
+
 
 def pipeline_config(cfg: dict):
     """The program's ``PipelineConfig`` from a configuration file. The
@@ -14,6 +42,7 @@ def pipeline_config(cfg: dict):
     from repro.core.rotation_forest import RotationForestConfig
     from repro.signal.pipeline import PipelineConfig
 
+    shape = signal_fields(cfg["signal"], PipelineConfig._fields)
     p = cfg["pipeline"]
     fixed = dict(mspca_level=reference.MSPCA_LEVEL,
                  wpd_level=reference.WPD_LEVEL, wavelet="db4",
@@ -28,6 +57,7 @@ def pipeline_config(cfg: dict):
         mspca_level=p["mspca_level"], denoise=p["denoise"],
         forest=RotationForestConfig(**p["forest"]),
         alarm_k=p["alarm_k"], alarm_m=p["alarm_m"], overlap=p["overlap"],
+        **shape,
     )
 
 

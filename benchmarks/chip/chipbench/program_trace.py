@@ -20,9 +20,9 @@ own metadata (JAX leaves metadata out of the cache key unless
 
 ``reduce`` gives ``tracing.reduce``'s ``Reduction`` of the same events,
 unchanged, and a ``Program``: span seconds, counts and summed args in
-the window; each scope segment's device seconds (the union of the
-intervals of the operations whose path holds it, clipped to the window:
-a ``while`` and its body overlap); and the first chip's idle gaps cut at
+the window; each scope segment's device seconds on the first chip (the
+union of the intervals of the operations whose path holds it, clipped to
+the window: a ``while`` and its body overlap); and its idle gaps cut at
 the edges of the host spans, each piece named by the innermost span that
 covers it (``tracing``'s rule, program spans included), adjacent pieces
 of one name merged, so that the idle time between two engine steps is
@@ -34,6 +34,7 @@ the ``bench.poll`` around them. Three-element events
 from __future__ import annotations
 
 import bisect
+import functools
 import glob
 import os
 import re
@@ -151,33 +152,70 @@ def attach_scopes(events: dict, hlo_text: str) -> int:
     return given
 
 
-def segments(path: str) -> set:
+@functools.cache
+def segments(path: str) -> frozenset:
     """The scope names on an operation's path, its last part (the
     primitive) left out and transform wrappers peeled:
     ``jit(f)/vmap(mspca)/jit(denoise)/eigh/jit(eigh)/eigh`` -> {"f",
-    "mspca", "denoise", "eigh"}; a bare ``gather`` -> {}."""
+    "mspca", "denoise", "eigh"}; a bare ``gather`` -> {}. Cached: a
+    window repeats a few thousand paths millions of times."""
     out = set()
     for part in path.split("/")[:-1]:
         while (m := _WRAPPED.match(part)):
             part = m.group(1)
         if part:
             out.add(part)
-    return out
+    return frozenset(out)
 
 
 class Program(NamedTuple):
     span_s: dict                   # seizure.* span -> seconds in the window
     span_count: dict               # seizure.* span -> occurrences
     span_args: dict                # seizure.* span -> {arg: summed value}
-    scope_s: dict                  # device -> {scope segment: seconds}
+    scope_s: dict                  # first device -> {scope segment: seconds}
     gaps: list                     # [(name, seconds)] on the first device,
                                    # cut at host span edges
 
     def first_scope_s(self) -> dict:
         return self.scope_s[tracing._first(self.scope_s)]
 
+    def span(self, name: str) -> float:
+        """Seconds of the span ``name`` in the window; raises where the
+        window has none (the mark was renamed or never ran)."""
+        if name not in self.span_s:
+            raise RuntimeError(f"no {name} span in the traced window: the "
+                               f"program's mark was renamed or never ran")
+        return self.span_s[name]
+
     def arg(self, span: str, name: str) -> float:
-        return self.span_args.get(span, {}).get(name, 0)
+        """The summed arg ``name`` of the span ``span`` (0 where the span
+        has no such arg); raises as ``span`` does."""
+        self.span(span)
+        return self.span_args[span].get(name, 0)
+
+    def scope(self, name: str) -> float:
+        """Device seconds of the scope segment ``name`` on the first chip;
+        raises where no operation in the window carries it."""
+        seconds = self.first_scope_s().get(name)
+        if seconds is None:
+            raise RuntimeError(f"no operation of scope {name!r} in the "
+                               f"traced window: the scope was renamed, never "
+                               f"ran, or the program's HLO lost it")
+        return seconds
+
+
+def scope_ms_per_execution(ctx: dict, scope: str, program: str):
+    """A reader's value: device milliseconds of ``scope`` per execution of
+    the jitted ``program`` in the window; None untraced; raises where the
+    trace has no execution of ``program`` or no operation of ``scope``."""
+    prog, trace = ctx.get("program"), ctx.get("trace")
+    if prog is None or trace is None:
+        return None
+    _, calls = trace.program(program)
+    if not calls:
+        raise RuntimeError(f"no execution of {program} in the trace: the "
+                           f"program was renamed or not run")
+    return prog.scope(scope) * 1e3 / calls
 
 
 def _scope_seconds(ops, lo: int, hi: int) -> dict:
@@ -234,9 +272,10 @@ def reduce(events: dict) -> tuple[tracing.Reduction, Program]:
         summed = span_args.setdefault(name, {})
         for k, v in (rest[0] if rest else {}).items():
             summed[k] = summed.get(k, 0) + v
-    scope_s = {d: _scope_seconds(dev["ops"], lo, hi)
-               for d, dev in events["devices"].items()
-               if tracing.DEVICE_PLANE.match(d)}
+    # The first chip's only: every reader reads it, and a four-chip
+    # window holds millions of operations.
+    first = tracing._first(red.devices)
+    scope_s = {first: _scope_seconds(events["devices"][first]["ops"], lo, hi)}
     host = [(n, s, e) for n, s, e in tracing._clip(plain_host, lo, hi)
             if n != tracing.WINDOW_SPAN]
     gaps = _name_idle(red.first.busy, host, lo, hi)

@@ -2,13 +2,16 @@
 description (Jukic & Subasi 2017, Sec. 2.1-2.6) and sharing no code with
 the program under test.
 
-  raw chunk (60, 3, 2048) -> MSPCA denoise of the 2048 x 180 matrix
-  (periodized db4 DWT to level 5 along samples, PCA across the 180
+  raw chunk (W, C, N) -> MSPCA denoise of the N x W·C matrix
+  (periodized db4 DWT to level 5 along samples, PCA across the W·C
   columns at every scale keeping 30 components, inverse DWT)
   -> level-4 db4 wavelet packet per window and channel, six statistics
-  per terminal node (288 features) -> z-score -> rotation forest
+  per terminal node (96·C features) -> z-score -> rotation forest
   (per-tree rotation, quantile-bin thresholds, depth-6 heap walk, mean
   leaf distribution, argmax) -> majority vote per chunk -> 3-of-5 alarm.
+
+At the paper's format (W, C, N) = (60, 3, 2048): a 2048 x 180 matrix and
+288 features. The shape is taken from the arrays, so any montage runs.
 
 Everything is float32 with every matrix product at ``HIGHEST``. The
 control runs the same code with ``low=True``: every stage's output and
@@ -114,7 +117,7 @@ def _pca_keep(c, num: Numerics):
 
 
 def denoise_chunk(chunk, num: Numerics):
-    """(60, 3, 2048) raw windows -> (60, 3, 2048) MSPCA-denoised."""
+    """(W, C, N) raw windows -> (W, C, N) MSPCA-denoised."""
     w, c, n = chunk.shape
     cols = chunk.reshape(w * c, n).astype(jnp.float32)   # one column per row
     mean = jnp.mean(cols, axis=1, keepdims=True)
@@ -150,7 +153,7 @@ def node_stats(coeffs):
 
 
 def wpd_features(wins, num: Numerics):
-    """(W, 3, 2048) windows -> (W, 288): 16 terminal nodes per channel in
+    """(W, C, N) windows -> (W, 96·C): 16 terminal nodes per channel in
     natural order (node 2i is the low branch of node i), 6 stats each."""
     nodes = [wins]
     for _ in range(WPD_LEVEL):
@@ -165,7 +168,7 @@ def wpd_features(wins, num: Numerics):
 
 @functools.partial(jax.jit, static_argnames=("num",))
 def chunk_features(chunks, num: Numerics = EXACT):
-    """(K, 60, 3, 2048) chunks -> (K, 60, 288) feature rows."""
+    """(K, W, C, N) chunks -> (K, W, 96·C) feature rows."""
     return jax.vmap(lambda ch: wpd_features(denoise_chunk(ch, num), num))(
         chunks)
 
@@ -174,7 +177,7 @@ BLOCK_CHUNKS = 32
 
 
 def features_in_blocks(chunks, num: Numerics = EXACT, block: int | None = None):
-    """(K, 60, 3, 2048) chunks, host or device -> (K, 60, 288) on the
+    """(K, W, C, N) chunks, host or device -> (K, W, 96·C) on the
     device, ``block`` (default ``BLOCK_CHUNKS``) chunks at a time so that
     the reference's memory stays small; the last block is padded with
     repeats so that one program serves every block."""

@@ -61,7 +61,8 @@ from chipbench import eeg, program, reference, spans
 
 class Prepared(NamedTuple):
     program: object               # the program's ScoringProgram
-    pool: np.ndarray              # (P, 60, 3, 2048) host chunks
+    pool: np.ndarray              # (P, chunk_windows, channels,
+                                  #  window_samples) host chunks
     served: reference.Served
 
 
@@ -75,22 +76,24 @@ class Fleet(NamedTuple):
 
 
 def prepare(cfg: dict, traffic: dict, seed: int, log) -> Prepared:
-    """The pool and the served program, from the seed."""
+    """The pool and the served program, from the seed. A configuration
+    the program cannot serve is refused before anything is made."""
+    pcfg = program.pipeline_config(cfg)
+    sig = eeg.Signal.of(cfg)
     key = eeg.key_from_seed(seed)
     k_pool, k_train, k_forest = jax.random.split(key, 3)
     pool_spec = traffic["pool"]
     pool = np.asarray(eeg.chunk_pool(
         k_pool, pool_spec["timelines"], pool_spec["interictal_chunks"],
-        cfg["patients"]))
+        cfg["patients"], sig))
     log(f"pool: {pool.shape[0]} chunks, {pool.nbytes} bytes on the host")
 
     t = cfg["served_forest"]
-    n = t["chunks_per_class"] * eeg.CHUNK
+    n = t["chunks_per_class"] * sig.chunk_windows
     wins, labels = jax.vmap(
-        lambda k, p: eeg.training_set(k, p, n_inter=n, n_pre=n))(
+        lambda k, p: eeg.training_set(k, p, n_inter=n, n_pre=n, sig=sig))(
         jax.random.split(k_train, t["patients"]), jnp.arange(t["patients"]))
-    feats = reference.features_in_blocks(
-        wins.reshape(-1, eeg.CHUNK, eeg.N_CHANNELS, eeg.WINDOW))
+    feats = reference.features_in_blocks(sig.as_chunks(wins))
     labels = labels.reshape(-1)
     jax.block_until_ready(feats)
     log(f"served forest: {labels.shape[0]} training windows of "
@@ -101,7 +104,7 @@ def prepare(cfg: dict, traffic: dict, seed: int, log) -> Prepared:
         reference.FitConfig(shards=1, trees_per_shard=fc["n_trees"],
                             subsets=fc["n_subsets"], depth=fc["depth"],
                             bins=fc["n_bins"], classes=fc["n_classes"]))
-    prog = program.scoring_program(served, program.pipeline_config(cfg))
+    prog = program.scoring_program(served, pcfg)
     jax.block_until_ready((prog.packed, served))
     log(f"program: {fc['n_trees']} trees of depth {fc['depth']}")
     return Prepared(prog, pool, served)
@@ -128,7 +131,7 @@ def open_fleet(prep: Prepared, cfg: dict, traffic: dict, devices: list,
     def engine():
         return SeizureEngine(prep.program, **opts)
 
-    _warm_up(engine(), prep.pool, opts)
+    _warm_up(engine(), prep.pool, eeg.Signal.of(cfg), opts)
     eng = engine()
     n = fleet_size(traffic)
     sessions = [eng.open_session(i) for i in range(n)]
@@ -141,15 +144,31 @@ def setup(cfg: dict, traffic: dict, seed: int, devices: list, log) -> Fleet:
                       devices, log)
 
 
-def _warm_up(engine, pool: np.ndarray, opts: dict) -> None:
+def hlo_text(fleet: Fleet) -> str:
+    """The compiled HLO text of the engine's step as the window runs it:
+    the engine's batch geometry and the configuration's signal."""
+    from repro.serving import api
+
+    eng, prog = fleet.engine, fleet.engine.program
+    sig = eeg.Signal.of(fleet.cfg)
+    b, d = eng.max_batch, eng.replay_depth
+    chunks = jax.ShapeDtypeStruct(
+        (b, d, eng.chunk_windows, sig.channels, sig.window_samples),
+        jnp.float32)
+    return api._jit_engine_step_megabatch.lower(
+        eng._state, chunks, jax.ShapeDtypeStruct((b, d), jnp.int32),
+        prog.packed, prog.feat_mean, prog.feat_std, cfg=prog.cfg,
+        use_pallas=eng.use_forest_kernel).compile().as_text()
+
+
+def _warm_up(engine, pool: np.ndarray, sig: eeg.Signal, opts: dict) -> None:
     """Every program the window runs, once: a full step at the fixed
     (B, D), eviction and admission of a waiting session, and a partial
     step."""
     b, d = opts["max_batch"], opts["replay_depth"]
     for i in range(b + 1):
-        engine.open_session(i).push(
-            pool[i % len(pool)][None].repeat(d if i < b else 1, 0).reshape(
-                -1, eeg.N_CHANNELS, eeg.WINDOW))
+        engine.open_session(i).push(sig.as_windows(
+            pool[i % len(pool)][None].repeat(d if i < b else 1, 0)))
     engine.poll(drain=False)
     engine.poll()
 
@@ -172,10 +191,11 @@ class Window(NamedTuple):
 class _Book:
     """What the generator pushed and what came back."""
 
-    def __init__(self, push_mode: str):
+    def __init__(self, push_mode: str, sig: eeg.Signal):
         if push_mode not in ("whole", "windows"):
             raise ValueError(f"unknown push mode {push_mode!r}")
         self.by_window = push_mode == "windows"
+        self.sig = sig
         self.due: dict = collections.defaultdict(list)
         self.pushed: dict = collections.defaultdict(list)
         self.scored: dict = collections.defaultdict(list)
@@ -191,13 +211,12 @@ class _Book:
         with rec.span("bench.push"):
             if not self.by_window:
                 start = time.perf_counter()
-                session.push(pool[first:first + k].reshape(
-                    -1, eeg.N_CHANNELS, eeg.WINDOW))
+                session.push(self.sig.as_windows(pool[first:first + k]))
                 dues = [start if due is None else due] * k
             else:
                 dues = []
                 for c in range(first, first + k):
-                    for w in range(eeg.CHUNK):
+                    for w in range(self.sig.chunk_windows):
                         start = time.perf_counter()
                         session.push(pool[c, w])
                     dues.append(start if due is None else due)
@@ -319,7 +338,7 @@ def run_window(fleet: Fleet, seconds: float, seed: int, rec: spans.Recorder
     else:
         raise ValueError(f"unknown arrivals {tr['arrivals']!r}")
     churn = float(tr.get("churn", 0.0))
-    book = _Book(tr.get("push", "whole"))
+    book = _Book(tr.get("push", "whole"), eeg.Signal.of(fleet.cfg))
     engine, pool, sessions = fleet.engine, fleet.pool, fleet.sessions
     position = {s.patient_id: i for i, s in enumerate(sessions)}
     next_id = max(position) + 1

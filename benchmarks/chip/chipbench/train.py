@@ -74,13 +74,13 @@ def fit_options(cfg: dict, devices: list) -> dict:
 
 def make_data(cfg: dict, seed: int, sharding) -> tuple[jax.Array, list]:
     """(fit key, [(windows, labels)] per patient placed by ``sharding``)."""
-    t = cfg["training_set"]
+    t, sig = cfg["training_set"], eeg.Signal.of(cfg)
     k_data, k_fit = jax.random.split(eeg.key_from_seed(seed))
     data = []
     for p in range(cfg["patients"]):
         w, y = eeg.training_set(jax.random.fold_in(k_data, p), p,
                                 n_inter=t["interictal_windows"],
-                                n_pre=t["preictal_windows"])
+                                n_pre=t["preictal_windows"], sig=sig)
         data.append(jax.device_put((w, y), sharding))
     return k_fit, jax.block_until_ready(data)
 
@@ -116,6 +116,11 @@ def run_window(job: Job, seconds: float, seed: int, rec: spans.Recorder
     return Window(t - t0, i, i * rows, kept)
 
 
+def hlo_text(job: Job) -> str:
+    """The compiled HLO text of the fit as the window runs it."""
+    return job.fit.lower(job.key, *job.data[0]).compile().as_text()
+
+
 def end_to_end(w: Window) -> dict:
     return {"train_windows_per_s": w.windows / w.seconds}
 
@@ -146,8 +151,7 @@ def reference_fit(job: Job, patient: int, key):
     """The reference's own answer for one fit: features of the patient's
     whole training set, then the MapReduce fit, on the first chip."""
     w, y = jax.device_put(job.data[patient], job.devices[0])
-    feats = reference.features_in_blocks(
-        w.reshape(-1, eeg.CHUNK, eeg.N_CHANNELS, eeg.WINDOW))
+    feats = reference.features_in_blocks(eeg.Signal.of(job.cfg).as_chunks(w))
     return reference.fit(key, feats.reshape(-1, feats.shape[-1]), y,
                          fit_config(job))
 
@@ -166,7 +170,8 @@ def heldout(job: Job, i: int, patient: int):
     key = jax.random.fold_in(jax.random.fold_in(job.key, 2**30), i)
     chunks, _ = eeg.timeline(
         key, patient,
-        n_inter_chunks=job.traffic["heldout_interictal_chunks"])
+        n_inter_chunks=job.traffic["heldout_interictal_chunks"],
+        sig=eeg.Signal.of(job.cfg))
     feats = reference.features_in_blocks(
         jax.device_put(chunks, job.devices[0]))
     return feats.reshape(-1, feats.shape[-1])
@@ -194,8 +199,7 @@ def control_fit(job: Job, patient: int, key):
     bfloat16, put in the program's place."""
     w, y = jax.device_put(job.data[patient], job.devices[0])
     feats = reference.features_in_blocks(
-        w.reshape(-1, eeg.CHUNK, eeg.N_CHANNELS, eeg.WINDOW),
-        reference.CONTROL)
+        eeg.Signal.of(job.cfg).as_chunks(w), reference.CONTROL)
     return reference.fit(key, feats.reshape(-1, feats.shape[-1]), y,
                          fit_config(job), reference.CONTROL)
 

@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
 import sys
 import time
@@ -79,9 +78,10 @@ def train_control(spec, seed: int, devices, names=TRAIN_ANSWERS) -> dict:
     import jax
     import numpy as np
 
-    from chipbench import reference, train
+    from chipbench import eeg, reference, train
 
     _, _, cfg, traffic = spec
+    sig = eeg.Signal.of(cfg)
     k_fit, data = train.make_data(cfg, seed, devices[0])
     job = train.Job(None, data, k_fit, list(devices), cfg, traffic)
     fcfg = train.fit_config(job)
@@ -92,8 +92,8 @@ def train_control(spec, seed: int, devices, names=TRAIN_ANSWERS) -> dict:
         key = jax.random.fold_in(k_fit, i)
         held = train.heldout(job, i, p)
         w, y = data[p]
-        feats = reference.features_in_blocks(
-            w.reshape(-1, 60, 3, 2048)).reshape(-1, 288)
+        feats = reference.features_in_blocks(sig.as_chunks(w))
+        feats = feats.reshape(-1, feats.shape[-1])
         truth = reference.fit(key, feats, y, fcfg)
         forest, mean, std = truth
         answers = {
@@ -104,8 +104,8 @@ def train_control(spec, seed: int, devices, names=TRAIN_ANSWERS) -> dict:
                 mean, std),
             "control": lambda: reference.fit(
                 key, reference.features_in_blocks(
-                    w.reshape(-1, 60, 3, 2048), reference.CONTROL
-                ).reshape(-1, 288), y, fcfg, reference.CONTROL),
+                    sig.as_chunks(w), reference.CONTROL
+                ).reshape(feats.shape), y, fcfg, reference.CONTROL),
             "half_rows": lambda: reference.fit(
                 key, _half_rows(feats, fcfg.shards),
                 _half_rows(y, fcfg.shards), fcfg),
@@ -140,9 +140,7 @@ def main() -> None:
 
     from chipbench import cell
 
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    cell.use_cache(ROOT)
     spec = cell.load_spec(ROOT, args.workload)
     devices = jax.devices()
     if devices[0].platform != "tpu":

@@ -22,7 +22,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
-import os  # noqa: E402
 import pathlib  # noqa: E402
 import sys  # noqa: E402
 
@@ -55,11 +54,7 @@ def main() -> None:
 
     import jax
 
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-
+    cell.use_cache(ROOT)
     visible = jax.devices()
     if visible[0].platform != "tpu":
         fail(f"JAX finds no TPU (platform {visible[0].platform!r})")

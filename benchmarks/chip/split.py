@@ -6,21 +6,12 @@ scopes: one traced window per seed, on the chip.
       --seeds 7 8 --seconds 30
 
 Runs the cell's set-up and one traced window as ``run.py --trace 1``
-does, without the comparison, and reads the trace through ``tracing``
-(the accepted per-layer metrics, by their own readers) and through
-``program_trace`` (the program's ``seizure.*`` spans with their args, and
-the device time of each ``jax.named_scope``). Prints one JSON object per
-seed: the accepted metrics, the split under the names of the metrics
-that would read it, the sums that show whether the spans and scopes
-cover their layers, the longest idle gaps named by program spans, and
-the compilations in the window.
-
-The chip's operation events carry no scope path (PERF.md section 6), so
-each operation of the step or the fit is given that of its instruction
-in the compiled program's HLO text (``program_trace.attach_scopes``). So
-that this text holds the program's scopes, the run makes metadata part
-of the compilation cache key: an entry of an older commit would bring
-back its own.
+does (``cell.measure``), without the comparison, and prints one JSON
+object per seed: the cell's per-layer metrics, each read by its own
+reader (``metrics/<name>.py``); the sums that show whether the program's
+spans and scopes cover their layers (``coverage``); the device time of
+every scope; the longest idle gaps named by program spans; and the
+compilations in the window.
 
 ``--record FILE`` also writes one step (serving) or one fit (training)
 of the first seed's trace as a small recorded trace for the CPU tests:
@@ -35,87 +26,43 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
-import shutil
 import sys
-import tempfile
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parents[1]
 
-SERVE_SPANS = ("fill", "assemble", "put", "dispatch", "readback", "events")
-SERVE_SCOPES = ("mspca", "eigh", "wpd", "vote", "ring")
-TRAIN_SCOPES = ("featurize", "mspca", "eigh", "wpd", "moments", "rotate",
-                "grow", "gather")
+# The marks that together cover a layer: the program's host spans of one
+# ``SeizureEngine`` step (beside ``bench.poll``), and the device stages of
+# the engine step or of a fit (beside its device time).
+COVERAGE = {
+    "serve": {"spans": ("fill", "assemble", "put", "dispatch", "readback",
+                        "events"),
+              "stages": ("mspca", "wpd", "vote", "ring")},
+    "train": {"stages": ("featurize", "moments", "rotate", "grow",
+                         "gather")},
+}
 
 
-def serve_split(red, prog, counters: dict) -> dict:
-    steps = counters["engine_steps"]
-    _, execs = red.program("_engine_step_megabatch")
-    chunks = prog.arg("seizure.assemble", "chunks")
+def coverage(red, prog, counters: dict, kind: str) -> dict:
+    """Per engine step (serving) or per fit (training): the sum of the
+    program's spans of a step, the sum of its device stages, and every
+    scope's device time; to set beside ``bench.poll`` and the step's or
+    fit's device time."""
+    if kind == "serve":
+        per = red.program("_engine_step_megabatch")[1]
+        host = counters["engine_steps"]
+    else:
+        per = host = counters["fits"]
     scope = prog.first_scope_s()
-    spans = {n: prog.span_s.get("seizure." + n, 0.0) * 1e3 / steps
-             for n in SERVE_SPANS}
-    scopes = {n: scope.get(n, 0.0) * 1e3 / execs for n in SERVE_SCOPES}
-    h2d = prog.arg("seizure.put", "bytes") + prog.arg("seizure.fill",
-                                                       "h2d_bytes")
-    metrics = {f"{n}_ms_per_step.replay": spans[n]
-               for n in ("fill", "assemble", "put", "readback")}
-    metrics.update({
-        "queue_wait_ms.replay":
-            prog.arg("seizure.assemble", "queue_wait_s") * 1e3 / chunks,
-        "h2d_mb_per_chunk.replay": h2d / 1e6 / chunks,
-    })
-    metrics.update({f"{n}_device_ms.replay": scopes[n]
-                    for n in ("mspca", "wpd", "vote")})
-    return {"metrics": metrics, "span_ms_per_step": spans,
-            "scope_ms_per_step": scopes,
-            "spans_sum_ms_per_step": sum(spans.values()),
-            "stages_sum_ms_per_step": sum(scopes[n] for n in (
-                "mspca", "wpd", "vote", "ring")),
-            "chunks_taken": chunks, "step_executions": execs,
-            "span_args": prog.span_args}
-
-
-def train_split(red, prog, counters: dict) -> dict:
-    fits = counters["fits"]
-    scope = prog.first_scope_s()
-    scopes = {n: scope.get(n, 0.0) * 1e3 / fits for n in TRAIN_SCOPES}
-    return {"metrics": {f"{n}_device_ms.train": scopes[n]
-                        for n in ("featurize", "rotate", "grow")},
-            "scope_ms_per_fit": scopes,
-            "stages_sum_ms_per_fit": sum(scopes[n] for n in (
-                "featurize", "moments", "rotate", "grow", "gather"))}
-
-
-SPLITS = {"serve": serve_split, "train": train_split}
-
-
-def serve_hlo(fleet) -> str:
-    """The compiled HLO text of the engine's step, as the window ran it."""
-    import jax
-    import jax.numpy as jnp
-    from repro.serving import api
-    from repro.signal import eeg_data
-
-    eng, prog = fleet.engine, fleet.engine.program
-    b, d = eng.max_batch, eng.replay_depth
-    chunks = jax.ShapeDtypeStruct((b, d, eng.chunk_windows,
-                                   eeg_data.N_CHANNELS, eeg_data.WINDOW),
-                                  jnp.float32)
-    return api._jit_engine_step_megabatch.lower(
-        eng._state, chunks, jax.ShapeDtypeStruct((b, d), jnp.int32),
-        prog.packed, prog.feat_mean, prog.feat_std, cfg=prog.cfg,
-        use_pallas=eng.use_forest_kernel).compile().as_text()
-
-
-def train_hlo(job) -> str:
-    """The compiled HLO text of the fit, as the window ran it."""
-    return job.fit.lower(job.key, *job.data[0]).compile().as_text()
-
-
-HLO_TEXT = {"serve": serve_hlo, "train": train_hlo}
+    out = {"scope_ms": {n: s * 1e3 / per for n, s in sorted(scope.items())},
+           "stages_sum_ms": sum(scope.get(n, 0.0) for n in
+                                COVERAGE[kind]["stages"]) * 1e3 / per}
+    if "spans" in COVERAGE[kind]:
+        out["spans_sum_ms"] = sum(
+            prog.span_s.get("seizure." + n, 0.0)
+            for n in COVERAGE[kind]["spans"]) * 1e3 / host
+    return out
 
 
 # What a recorded excerpt covers: one engine step (from one
@@ -173,57 +120,35 @@ def main() -> None:
     sys.path.insert(0, str(ROOT / "src"))
     sys.path.insert(0, str(BENCH_DIR))
 
-    from chipbench import cell, compiles, program_trace, spans
+    from chipbench import cell
 
     bench, entry, cfg, traffic = cell.load_spec(ROOT, args.workload)
     import jax
 
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    cell.use_cache(ROOT)
     devices = jax.devices()[:entry["chips"]]
-    driver = cell.DRIVERS[cfg["kind"]]
+    kind = cfg["kind"]
+    driver = cell.DRIVERS[kind]
     for seed in args.seeds:
         state = driver.setup(cfg, traffic, seed, devices, cell.log)
-        rec = spans.Recorder(True)
-        trace_dir = tempfile.mkdtemp(prefix="split-trace-")
-        try:
-            jax.profiler.start_trace(trace_dir)
-            with compiles.Counter() as counter:
-                window = driver.run_window(state, args.seconds, seed, rec)
-            jax.profiler.stop_trace()
-            events = program_trace.load(trace_dir)
-            scoped = program_trace.attach_scopes(
-                events, HLO_TEXT[cfg["kind"]](state))
-            red, prog = program_trace.reduce(events)
-        finally:
-            shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = {"spans": {n: (rec.seconds[n], rec.calls[n])
-                         for n in rec.seconds},
-               "counters": rec.counters, "window_s": window.seconds,
-               "trace": red}
-        accepted = {m["name"]: cell.read_metric(m["name"], ctx)
-                    for m in bench["per_layer"]
-                    if cell.applies(m, args.workload)}
+        m = cell.measure(driver, state, args.seconds, seed, True, devices)
+        prog = m.program
         gaps = sorted(prog.gaps, key=lambda g: -g[1])[:12]
         print(json.dumps({
             "workload": args.workload, "seed": seed,
-            "window_s": window.seconds, "counters": rec.counters,
-            "programs_in_window": counter.programs,
-            "program_spans": sum(prog.span_count.values()),
-            "scoped_ops": scoped,
-            "accepted": accepted,
-            **SPLITS[cfg["kind"]](red, prog, rec.counters),
+            "window_s": m.window.seconds, "counters": m.rec.counters,
+            "programs_in_window": m.counter.programs,
+            "program_spans": prog.span_count,
+            "metrics": cell.per_layer(bench, args.workload, m.context()),
+            **coverage(m.trace, prog, m.rec.counters, kind),
             "idle_gaps": [[n, s] for n, s in gaps],
             "gap_s_by_name": _by_name(prog.gaps),
         }), flush=True)
         if args.record:
             pathlib.Path(args.record).write_text(
-                json.dumps(excerpt(events, cfg["kind"])))
+                json.dumps(excerpt(m.events, kind)))
             args.record = None
-        del state, events
+        del state, m
 
 
 def _by_name(gaps) -> dict:

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
 import sys
 
@@ -43,9 +42,7 @@ def main() -> None:
 
     from chipbench import cell, serve, spans
 
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    cell.use_cache(ROOT)
     if jax.devices()[0].platform != "tpu":
         sys.exit("sweep: JAX finds no TPU")
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())

@@ -2,10 +2,12 @@
 (``chipbench/program_trace.py``): ``seizure.*`` host spans with their
 args, device time per ``jax.named_scope`` segment, idle gaps put down to
 program spans; on a synthetic trace, on a recorded chip trace of each
-cell, and on the older recorded trace that has no program marks."""
+cell, and on the older recorded trace that has no program marks; and the
+per-layer metrics that read them (``metrics/<name>.py``)."""
 
 from __future__ import annotations
 
+import functools
 import json
 import pathlib
 import sys
@@ -21,6 +23,8 @@ from chipbench import cell, program_trace, tracing  # noqa: E402
 STEP = "jit(_engine_step_megabatch)"
 EIGH = f"{STEP}/vmap(mspca)/vmap(jit(denoise_windows))/jit(denoise)/eigh/jit(eigh)/eigh"
 RECORDED = BENCH_DIR / "testdata" / "trace_program_small.json"
+ROOT = BENCH_DIR.parents[1]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 
 
 def synthetic() -> dict:
@@ -184,22 +188,86 @@ def test_excerpt_is_one_step_under_its_own_window():
     assert prog.first_scope_s()["ring"] == pytest.approx(2300e-9)
 
 
+@functools.cache
+def _recorded(workload: str) -> dict:
+    """A reader's ``ctx`` for one step or one fit of a traced run on a TPU
+    v5 lite (``split.py --record``)."""
+    rec = json.loads(RECORDED.read_text())[workload]
+    red, prog = program_trace.reduce(split.expand(rec))
+    return {"spans": {n: (s, red.host_count[n]) for n, s in red.host_s.items()},
+            "counters": rec["counters"], "window_s": red.window_s,
+            "trace": red, "program": prog}
+
+
 @pytest.mark.parametrize("workload", ["serve.reconnect", "train.mapreduce4"])
 def test_recorded_chip_trace_gives_every_split_metric(workload):
     assert RECORDED.stat().st_size < 1 << 20
-    # One step or one fit of a traced run on a TPU v5 lite (split.py --record).
-    rec = json.loads(RECORDED.read_text())[workload]
-    red, prog = program_trace.reduce(split.expand(rec))
+    ctx = _recorded(workload)
+    got = cell.per_layer(BENCH, workload, ctx)
+    assert set(PROGRAM_READINGS[workload]) <= set(got)
+    assert all(got[n] > 0 for n in PROGRAM_READINGS[workload]), got
     kind = "serve" if workload.startswith("serve") else "train"
-    out = split.SPLITS[kind](red, prog, rec["counters"])
-    assert out["metrics"] and all(v > 0 for v in out["metrics"].values()), out
+    cov = split.coverage(ctx["trace"], ctx["program"], ctx["counters"], kind)
     # The spans and scopes cover their layers.
     if kind == "serve":
-        seconds, calls = red.program("_engine_step_megabatch")
+        seconds, calls = ctx["trace"].program("_engine_step_megabatch")
         assert calls == 1
-        assert 0.95 * seconds * 1e3 <= out["stages_sum_ms_per_step"] <= seconds * 1e3
-        poll = red.host_s["bench.poll"] * 1e3
-        assert 0.95 * poll <= out["spans_sum_ms_per_step"] <= poll
+        assert 0.95 * seconds * 1e3 <= cov["stages_sum_ms"] <= seconds * 1e3
+        poll = got["poll_ms_per_step.replay"]
+        assert 0.95 * poll <= cov["spans_sum_ms"] <= poll
+        stages = sum(got[f"{n}_device_ms.replay"]
+                     for n in ("mspca", "wpd", "vote"))
+        assert stages >= 0.98 * got["step_device_ms.replay"]
+        host = sum(got[f"{n}_ms_per_step.replay"]
+                   for n in ("fill", "assemble", "put", "readback"))
+        assert host <= poll
     else:
-        busy = red.first.busy_s * 1e3
-        assert 0.95 * busy <= out["stages_sum_ms_per_fit"] <= busy
+        busy = got["fit_device_ms.train"]
+        assert 0.95 * busy <= cov["stages_sum_ms"] <= busy
+        stages = sum(got[f"{n}_device_ms.train"]
+                     for n in ("featurize", "rotate", "grow"))
+        assert stages >= 0.95 * busy
+        assert got["grow_device_ms.train"] > 30
+
+
+# The split of the recorded trace as its arithmetic gave it before the
+# metrics' readers took it over (ms, or MB per chunk).
+PROGRAM_READINGS = {
+    "serve.reconnect": {
+        "fill_ms_per_step.replay": 55.452412,
+        "assemble_ms_per_step.replay": 394.545755,
+        "put_ms_per_step.replay": 6.66298,
+        "readback_ms_per_step.replay": 1472.269353,
+        "queue_wait_ms.replay": 13386.909989726535,
+        "h2d_mb_per_chunk.replay": 1.5414721142857142,
+        "mspca_device_ms.replay": 1396.988726,
+        "wpd_device_ms.replay": 32.670432,
+        "vote_device_ms.replay": 5.121087,
+    },
+    "train.mapreduce4": {
+        "featurize_device_ms.train": 167.118617,
+        "rotate_device_ms.train": 99.211844,
+        "grow_device_ms.train": 64.115084,
+    },
+}
+READERS = [(w, n) for w, names in PROGRAM_READINGS.items() for n in names]
+
+
+@pytest.mark.parametrize("workload,name", READERS, ids=[n for _, n in READERS])
+def test_reader_gives_the_split_of_the_recorded_trace(workload, name):
+    value = cell.read_metric(name, _recorded(workload))
+    assert value == pytest.approx(PROGRAM_READINGS[workload][name], rel=1e-12)
+
+
+@pytest.mark.parametrize("workload,name", READERS, ids=[n for _, n in READERS])
+def test_reader_fails_loudly_without_its_mark(workload, name):
+    ctx = dict(_recorded(workload))
+    # Untraced: nothing to read.
+    assert cell.read_metric(name, dict(ctx, program=None)) is None
+    # Traced, but the program wrote no spans and its HLO gave no scopes.
+    bare = ctx["program"]._replace(
+        span_s={}, span_count={}, span_args={},
+        scope_s={d: {} for d in ctx["program"].scope_s})
+    with pytest.raises(RuntimeError, match="no "):
+        cell.read_metric(name, dict(ctx, program=bare))
+
